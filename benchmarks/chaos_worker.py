@@ -90,6 +90,8 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-root", default=None,
                     help="checkpoint scratch dir (default: a tmp dir)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     ckpt_root = args.ckpt_root
     if ckpt_root is None:

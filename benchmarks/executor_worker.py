@@ -51,7 +51,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.allreduce import all_to_all_flat, allreduce_flat
 from repro.core.autotune import choose, schedule_for
 from repro.core.cost_model import (HOST_CPU, choose_a2a,
@@ -271,6 +271,8 @@ def main():
                          "write a Chrome trace + metrics snapshot + "
                          "model-error report next to --out")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ops = args.op or ["sum", "max", "a2a"]
 
     n = len(jax.devices())

@@ -139,6 +139,8 @@ def main():
     ap.add_argument("--out", required=True)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n = len(jax.devices())
     mesh = Mesh(np.array(jax.devices()).reshape(n, 1), ("data", "model"))

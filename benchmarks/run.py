@@ -38,6 +38,12 @@ dimensionless ratios vs a same-host solo baseline, see docs/serving.md).
 
 Protocol CSV rows go to stdout via ``repro.obs.log.data``; diagnostics
 go to stderr as logfmt lines filtered by ``REPRO_LOG``.
+
+Every mode here runs on the host CPU only: the spawned workers force
+``JAX_PLATFORMS=cpu`` (8 forced host devices where a mesh is needed), so
+their times are XLA CPU backend times and never device metrics.  Until
+an on-chip benchmark mode exists (ROADMAP S1), the chip is reached only
+through ``python chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -168,15 +174,7 @@ def _spawn_8dev(script: str, extra_args=(), timeout=1800):
 
 def wallclock_8dev():
     """Real wall-clock of the JAX ppermute executor on 8 host devices."""
-    script = os.path.join(os.path.dirname(__file__), "wallclock_worker.py")
-    res = _spawn_8dev(script, timeout=900)
-    if res.returncode != 0:
-        log.error("worker_failed", worker="wallclock",
-                  stderr=res.stderr[-200:])
-        return
-    for line in res.stdout.strip().splitlines():
-        if line.startswith("wall,"):
-            data(line)
+    _worker_bench("wallclock_worker.py", "wall", (), timeout=900)
 
 
 def _worker_bench(script_name: str, prefix: str, extra, timeout=1800) -> None:

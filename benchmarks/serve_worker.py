@@ -151,6 +151,8 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--batch-slots", type=int, default=4)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # the mix is the SAME in smoke and full runs -- smoke only trims the
     # QPS grid -- so a smoke row in CI and the committed full-run row

@@ -28,6 +28,8 @@ def main():
         "user cache dir)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run_tuning(smoke=args.smoke, out=args.out, cache_path=args.cache)
 
 

@@ -18,7 +18,7 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.allreduce import allreduce_flat
 from repro.core.schedule import build_generalized, build_ring, max_r
 from repro.obs.log import data
@@ -35,6 +35,8 @@ def bench(fn, x, iters=30):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n = len(jax.devices())
     mesh = jax.make_mesh((n,), ("data",))
     rng = np.random.default_rng(0)

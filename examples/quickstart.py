@@ -52,7 +52,7 @@ def main():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as Psp
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.allreduce import allreduce_tree
 
     n = len(jax.devices())

@@ -39,7 +39,6 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
-from repro.compat import tree_flatten_with_path
 import numpy as np
 
 
@@ -52,7 +51,7 @@ def _sha256(path: str) -> str:
 
 
 def _leaf_paths(tree) -> Dict[str, Any]:
-    flat = tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     out = {}
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
@@ -244,7 +243,7 @@ def restore(ckpt_dir: str, like: Dict[str, Any],
             continue
         loaded = {k: np.load(os.path.join(d, have[k]["file"]))
                   for k in want}
-        flat, treedef = tree_flatten_with_path(tree)
+        flat, treedef = jax.tree.flatten_with_path(tree)
         leaves = []
         for path, leaf in flat:
             key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

@@ -5,8 +5,8 @@ Maps the paper's permutation-composition communication model onto JAX SPMD:
 * every communication operator ``t_g`` is a static ``lax.ppermute``
   (a cyclic shift for ``CyclicGroup`` -- the native pattern of a TPU ICI
   ring/torus; a pairwise exchange for ``HypercubeGroup``);
-* every distributed vector is one row of a single stacked ``(R, u)``
-  per-device buffer;
+* every distributed vector is one ``(u,)`` row; a step's outgoing rows
+  travel as one flat concatenation, sliced back into rows on arrival;
 * combines are fused local adds (the Pallas ``combine_n`` kernel on TPU).
 
 All functions below must be called *inside* ``jax.shard_map`` (manual SPMD)
@@ -60,8 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
-
 from .autotune import choose, schedule_for
 from .cost_model import (Fabric, TPU_V5E_ICI, choose_a2a, choose_n_buckets,
                          ragged_choose_n_buckets)
@@ -83,8 +81,8 @@ CombineFn = CombineLike   # legacy alias; combine= is monoid-aware now
 
 def axis_size(axis_name: AxisName) -> int:
     if isinstance(axis_name, (tuple, list)):
-        return math.prod(compat.axis_size(a) for a in axis_name)
-    return compat.axis_size(axis_name)
+        return math.prod(lax.axis_size(a) for a in axis_name)
+    return lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +119,7 @@ def _ragged_extract_index(sizes: Tuple[int, ...], w: int) -> np.ndarray:
 
     Only the general (caller-provided, possibly unbalanced) allgatherv
     path needs an index gather; the balanced split used everywhere else
-    goes through the reshape-based :func:`exact_chunks` /
+    goes through the slice-based :func:`_flat_chunks` /
     :func:`_ragged_flatten`, which build no O(m) constants.  Caching is
     capped per entry (vectors above ``_EXTRACT_CACHE_MAX_ELEMS`` are
     rebuilt per call, never pinned) and by entry count; the worst-case
@@ -132,61 +130,71 @@ def _ragged_extract_index(sizes: Tuple[int, ...], w: int) -> np.ndarray:
     return _cached_extract_index(sizes, w)
 
 
+def _flat_chunks(x: jnp.ndarray, P: int) -> Tuple[jnp.ndarray, int]:
+    """The balanced exact split as one flat ``(P * w,)`` buffer, ``w =
+    ceil(m / P)``: chunk ``c`` occupies ``[c * w, (c + 1) * w)`` with its
+    ``ragged_sizes(m, P)[c]`` valid elements first and a zero tail.
+    Returns ``(flat, w)``; for ``P | m`` the buffer is ``x`` itself.
+
+    Built from 1-D slices and one concatenate.  The executors index this
+    buffer with 1-D (dynamic) slices and never row-slice a ``(P, w)``
+    matrix: the TPU compiler's time for a row slice of a reshaped 2-D
+    buffer grows with its size (tens of seconds per 64 Mi elements),
+    while a 1-D slice compiles in constant time.
+    """
+    m = x.shape[0]
+    if m % P == 0 and m:
+        return x, m // P
+    u, rem = divmod(m, P)
+    w = u + 1                                   # ceil(m / P); rem >= 1
+    zero = jnp.zeros((1,), x.dtype)
+    parts = [x[:rem * w]]
+    for c in range(P - rem):
+        start = rem * w + c * u
+        parts += [x[start:start + u], zero]
+    return jnp.concatenate(parts), w
+
+
 def exact_chunks(x: jnp.ndarray, P: int):
     """Split a flat vector into the ``(P, u_max)`` chunk buffer of the
     balanced exact split (a plain reshape when ``P`` divides ``m``);
     returns ``(chunks, m)``.  Public counterpart of
     :func:`repro.core.schedule.ragged_sizes`: row ``c`` holds chunk
     ``c``'s ``sizes[c]`` valid elements, zero-filled to the common
-    width.  Used by the executors here and by the zero1 optimizer to
-    slice parameters with the same geometry as their gradient shards.
-
-    The balanced split is two reshapes: the first ``rem = m % P`` chunks
-    are full rows of width ``u + 1``, the rest are rows of width ``u``
-    plus one zero column -- no O(m) gather or index constant.
+    width.  Used by the zero1 optimizer to slice parameters with the
+    same geometry as their gradient shards (the executors keep the flat
+    form, :func:`_flat_chunks`).
     """
-    m = x.shape[0]
-    if m % P == 0 and m:
-        return x.reshape(P, m // P), m
-    u, rem = divmod(m, P)
-    w = u + 1                                   # ceil(m / P); rem >= 1
-    big = x[:rem * w].reshape(rem, w)
-    small = x[rem * w:].reshape(P - rem, u) if u else \
-        jnp.zeros((P - rem, 0), x.dtype)
-    small = jnp.concatenate(
-        [small, jnp.zeros((P - rem, 1), x.dtype)], axis=1)
-    return jnp.concatenate([big, small], axis=0), m
+    flat, w = _flat_chunks(x, P)
+    return flat.reshape(P, w), x.shape[0]
 
 
-def _ragged_flatten(stacked: jnp.ndarray, m: int) -> jnp.ndarray:
-    """Inverse of :func:`exact_chunks` for a ``(P, w)`` buffer whose rows
-    are reduced chunks in chunk order: exact ``(m,)`` concatenation
-    (again two reshapes -- the zero tails are sliced off, not gathered).
-    """
-    P, w = stacked.shape
+def _ragged_flatten(flat: jnp.ndarray, m: int, P: int) -> jnp.ndarray:
+    """Inverse of :func:`_flat_chunks` for a ``(P * w,)`` buffer of
+    reduced chunks in chunk order: exact ``(m,)`` concatenation of the
+    valid prefixes (1-D slices; the zero tails are dropped, not
+    gathered)."""
+    w = flat.shape[0] // P
     if m == P * w:
-        return stacked.reshape(-1)
+        return flat
     u, rem = divmod(m, P)
     if w != u + 1:
         raise ShapeError("_ragged_flatten: row width != ceil(m / P)",
                          expected=u + 1, actual=w)
-    big = stacked[:rem].reshape(-1)
-    small = stacked[rem:, :u].reshape(-1)
-    return jnp.concatenate([big, small])
+    return jnp.concatenate([flat[:rem * w]] +
+                           [flat[c * w:c * w + u] for c in range(rem, P)])
 
 
-def _lazy_init_rows(chunks: jnp.ndarray, plan: ExecPlan, d) -> List:
-    """Per-slot initial rows as *lazy* dynamic slices of the local chunk
-    buffer: row k is ``chunks[init_rows[k, d]]``, left as a dynamic-slice
-    op for XLA to fuse into its first consumer (the old executor
-    materialized the whole (R0, u) gather up front).  Unwritten slots
-    start as None."""
+def _lazy_init_rows(flat: jnp.ndarray, w: int, plan: ExecPlan, d) -> List:
+    """Per-slot initial rows as *lazy* dynamic slices of the flat chunk
+    buffer: row k is chunk ``init_rows[k, d]``, i.e. ``flat[c * w:(c + 1)
+    * w]``, left as a dynamic-slice op for XLA to fuse into its first
+    consumer.  Unwritten slots start as None."""
     rows: List = []
     for k in range(plan.n_rows0):
         idx = lax.dynamic_index_in_dim(jnp.asarray(plan.init_rows[k]), d,
                                        keepdims=False)
-        rows.append(lax.dynamic_index_in_dim(chunks, idx, axis=0,
-                                             keepdims=False))
+        rows.append(lax.dynamic_slice_in_dim(flat, idx * w, w))
     return rows + [None] * (plan.n_slots - plan.n_rows0)
 
 
@@ -224,19 +232,23 @@ def _linear_axis_index(axis_name: AxisName):
 
 
 def _final_gather(rows: List, plan: ExecPlan, d) -> jnp.ndarray:
-    """One dynamic gather putting the reduced rows into chunk order.
+    """Put the reduced rows into chunk order: the flat ``(P * w,)``
+    concatenation of chunk 0, 1, ... ``P - 1``.
 
     The final placement is device-dependent (chunk c sits in slot
-    ``final_rows[c, d]``), so this pass cannot be static; the slot ->
-    stack-position remap is, and composes with the table.
+    ``final_rows[c, d]``), so each chunk is one dynamic 1-D slice of the
+    concatenated rows; the slot -> position remap is static and
+    composes with the table.
     """
     used = np.unique(plan.final_rows[plan.final_rows >= 0])
     pos = np.full(plan.n_slots, -1, dtype=np.int32)
     pos[used] = np.arange(len(used), dtype=np.int32)
-    tbl = pos[plan.final_rows]                      # (P, P) stack positions
-    full = jnp.stack([rows[int(s)] for s in used])
+    tbl = pos[plan.final_rows]                      # (P, P) row positions
+    w = rows[int(used[0])].shape[0]
+    full = jnp.concatenate([rows[int(s)] for s in used])
     order = jnp.take(jnp.asarray(tbl), d, axis=1)   # (P,)
-    return jnp.take(full, order, axis=0)
+    return jnp.concatenate([lax.dynamic_slice_in_dim(full, order[c] * w, w)
+                            for c in range(tbl.shape[0])])
 
 
 # ---------------------------------------------------------------------------
@@ -275,16 +287,17 @@ def allreduce_flat(x: jnp.ndarray, axis_name: AxisName,
     if accum_dtype is not None:
         x = x.astype(accum_dtype)
     x = monoid.prepare(x, P)
-    chunks, m = exact_chunks(x, P)                        # (P, u_max)
+    m = x.shape[0]
+    flat, w = _flat_chunks(x, P)                          # (P * u_max,)
     plan = compile_plan(sched)
     d = _linear_axis_index(axis_name)
-    rows = _lazy_init_rows(chunks, plan, d)
+    rows = _lazy_init_rows(flat, w, plan, d)
     bucket_rows, u = _bucket_rows(rows, n_buckets)
     bucket_rows = execute(plan, bucket_rows, axis_name, combine=combine,
                           tag=tag)
     rows = _merge_rows(bucket_rows, u)
-    out = _final_gather(rows, plan, d)                     # (P, u_max)
-    out = _ragged_flatten(out, m)                          # exact (m,)
+    out = _final_gather(rows, plan, d)                     # (P * u_max,)
+    out = _ragged_flatten(out, m, P)                       # exact (m,)
     out = monoid.finalize(out, P)
     return out.astype(orig_dtype)
 
@@ -320,10 +333,10 @@ def reduce_scatter_flat(x: jnp.ndarray, axis_name: AxisName,
     if accum_dtype is not None:
         x = x.astype(accum_dtype)
     x = monoid.prepare(x, P)
-    chunks, _ = exact_chunks(x, P)
+    flat, w = _flat_chunks(x, P)
     plan = compile_plan(sched)
     d = _linear_axis_index(axis_name)
-    rows = _lazy_init_rows(chunks, plan, d)
+    rows = _lazy_init_rows(flat, w, plan, d)
     bucket_rows, u = _bucket_rows(rows, n_buckets)
     bucket_rows = execute(plan, bucket_rows, axis_name, combine=combine)
     rows = _merge_rows(bucket_rows, u)
@@ -372,16 +385,16 @@ def all_gather_flat(chunk: jnp.ndarray, axis_name: AxisName,
     bucket_rows = execute(plan, bucket_rows, axis_name)
     rows = _merge_rows(bucket_rows, u)
     d = _linear_axis_index(axis_name)
-    out = _final_gather(rows, plan, d)                     # (P, w)
+    out = _final_gather(rows, plan, d)                     # (P * w,)
     if sizes is None:
-        return out.reshape(-1)
+        return out
     total = sum(sizes)
-    w = int(out.shape[1])
+    w = int(chunk.shape[0])
     if sizes == ragged_sizes(total, P) and \
             (total == P * w or w == total // P + 1):
-        return _ragged_flatten(out, total)     # balanced: two reshapes
+        return _ragged_flatten(out, total, P)  # balanced: 1-D slices
     idx = _ragged_extract_index(sizes, w)      # general allgatherv
-    return jnp.take(out.reshape(-1), jnp.asarray(idx))
+    return jnp.take(out, jnp.asarray(idx))
 
 
 def all_to_all_flat(x: jnp.ndarray, axis_name: AxisName, *,
@@ -423,13 +436,12 @@ def all_to_all_flat(x: jnp.ndarray, axis_name: AxisName, *,
     if kind == "auto":
         kind = choose_a2a(P, m * x.dtype.itemsize, fabric)
     plan = compile_a2a_plan(P, kind)
-    chunks = x.reshape(P, m // P)
     d = _linear_axis_index(axis_name)
-    rows = _lazy_init_rows(chunks, plan, d)
+    rows = _lazy_init_rows(x, m // P, plan, d)
     bucket_rows, u = _bucket_rows(rows, n_buckets)
     bucket_rows = execute(plan, bucket_rows, axis_name)
     rows = _merge_rows(bucket_rows, u)
-    return _final_gather(rows, plan, d).reshape(-1)
+    return _final_gather(rows, plan, d)
 
 
 # ---------------------------------------------------------------------------
@@ -564,10 +576,10 @@ def hierarchical_allreduce_flat(x: jnp.ndarray, axis_names: Sequence[str],
                          f"{topo.describe()}", expected=topo.n_levels,
                          actual=len(axis_names))
     for name, lvl in zip(axis_names, topo.levels):
-        if compat.axis_size(name) != lvl.size:
+        if lax.axis_size(name) != lvl.size:
             raise ShapeError(f"axis {name!r} size != topology level "
                              f"{lvl.name}", expected=lvl.size,
-                             actual=compat.axis_size(name))
+                             actual=lax.axis_size(name))
     monoid, impl = resolve_combine(combine)
     if monoid.pre_scale is not None or monoid.post_divide:
         # strip the bookends off what the per-level executors see (they

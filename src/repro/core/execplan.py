@@ -284,61 +284,60 @@ def compile_plan(sched: Schedule) -> ExecPlan:
 #  vectorized JAX executor with multi-bucket software pipelining
 # ---------------------------------------------------------------------------
 
-def _take(buf, idx: np.ndarray):
-    """Static row gather; a slice for contiguous index ranges."""
-    n = len(idx)
-    if n and (idx == np.arange(idx[0], idx[0] + n)).all():
-        if idx[0] == 0 and n == int(buf.shape[0]):
-            return buf
-        return buf[int(idx[0]):int(idx[0]) + n]
-    return buf[idx]
+def _row(buf, i: int, u: int):
+    """Row ``i`` of a flat buffer of ``u``-element rows: a static 1-D
+    slice.  The executor keeps every multi-row buffer flat because the
+    TPU compiler's time for row slices of a 2-D buffer grows with the
+    buffer's size, while 1-D slices compile in constant time."""
+    i = int(i)
+    return buf if buf.shape[0] == u else buf[i * u:(i + 1) * u]
 
 
 def _pallas_combine(jobs, monoid: Monoid = None):
     """Fuse all (res, arr) pairwise combines of a tick into ONE Pallas
     ``combine_n`` call over the concatenated flat buffers.
 
-    ``jobs`` is a list of (res_mat, arr_mat) with matching shapes; the
-    K-way kernel (K=2 here) reads both operands once from HBM and writes
-    the combine (``monoid.kind``: add / max / min -- all one VPU op per
-    element over the same VMEM tiling), instead of one chained elementwise
-    dispatch per bucket.  Interpret mode is used automatically off-TPU.
+    ``jobs`` is a list of (res_rows, arr_rows): equally long lists of
+    flat rows; returns the combined rows, one list per job.  The K-way
+    kernel (K=2 here) reads both operands once from HBM and writes the
+    combine (``monoid.kind``: add / max / min -- all one VPU op per
+    element over the same VMEM tiling), instead of one chained
+    elementwise dispatch per bucket.  Interpret mode is used off-TPU
+    (:func:`repro.kernels.ops.interpret`).
 
-    Some shard_map replication checkers have no rule for ``pallas_call``
-    (jax <= 0.4.x ``check_rep``); there the kernel cannot trace and we
-    fall back to the identical-numerics elementwise op (same fp32
-    pairwise combines).  Build the shard_map with ``check_vma=False``
-    (see :func:`repro.compat.shard_map`) to route through the real
-    kernel.
+    The kernel's output carries its operands' varying manual axes, so on
+    the TPU it traces inside a ``shard_map`` with the default
+    ``check_vma=True``.  JAX's Pallas interpreter does not trace under
+    that checker, so an off-TPU caller that forces ``combine="pallas"``
+    builds its ``shard_map`` with ``check_vma=False`` ("auto" picks the
+    elementwise op there and needs neither).
     """
-    import jax
     import jax.numpy as jnp
 
+    from repro.kernels import ops as kernel_ops
     from repro.kernels.fused_combine import _BLOCK, combine_n
 
     from .monoid import SUM
     if monoid is None:
         monoid = SUM
-    op = monoid.jax_op
-    res_flat = jnp.concatenate([r.reshape(-1) for r, _ in jobs])
-    arr_flat = jnp.concatenate([a.reshape(-1) for _, a in jobs])
+    res_flat = jnp.concatenate([r for res, _ in jobs for r in res])
+    arr_flat = jnp.concatenate([a for _, arr in jobs for a in arr])
     n = res_flat.shape[0]
     dt = res_flat.dtype
     # max/min never lose precision to the accumulator: skip the widening
     accum = jnp.float32 if (monoid.kind == "add"
                             and jnp.issubdtype(dt, jnp.inexact)) else dt
     block = min(_BLOCK, 128 * max(1, math.ceil(n / 128)))
-    try:
-        out = combine_n(jnp.stack([res_flat, arr_flat]), accum_dtype=accum,
-                        interpret=jax.default_backend() != "tpu",
-                        block=block, op=monoid.kind)
-    except NotImplementedError:
-        return [op(r, a) for r, a in jobs]
+    out = combine_n(jnp.stack([res_flat, arr_flat]), accum_dtype=accum,
+                    interpret=kernel_ops.interpret(), block=block,
+                    op=monoid.kind)
     outs, off = [], 0
-    for r, _ in jobs:
-        sz = int(np.prod(r.shape))
-        outs.append(out[off:off + sz].reshape(r.shape))
-        off += sz
+    for res, _ in jobs:
+        rows = []
+        for r in res:
+            rows.append(out[off:off + r.shape[0]])
+            off += r.shape[0]
+        outs.append(rows)
     return outs
 
 
@@ -377,12 +376,12 @@ def execute(plan: ExecPlan, bucket_rows: Sequence[List], axis_name, *,
     gradient bucket (e.g. ``"grad_bucket3"``) so per-bucket dispatches
     are identifiable in the trace timeline.
     """
-    import jax
+    from repro.kernels import ops as kernel_ops
 
     monoid, impl = resolve_combine(combine)
     if impl == "auto":
         impl = "pallas" if (monoid.fuses_pallas
-                            and jax.default_backend() == "tpu") else "op"
+                            and not kernel_ops.interpret()) else "op"
     if impl == "pallas" and not monoid.fuses_pallas:
         raise ValueError(f"monoid {monoid.name!r} has no fused Pallas "
                          f"kernel; use the elementwise path")
@@ -408,6 +407,9 @@ def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
     import jax.numpy as jnp
     from jax import lax
 
+    # every row has the bucket width u; multi-row buffers stay flat
+    u = next(r.shape[0] for rows in bucket_rows for r in rows
+             if r is not None)
     for active in ticks:
         # 1) issue phase: stage every active bucket's communication
         rx = {}
@@ -415,7 +417,7 @@ def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
             sp = plan.steps[s]
             if sp.n_tx:
                 rows = bucket_rows[j]
-                tx = jnp.stack([rows[i] for i in sp.tx_slots])
+                tx = jnp.concatenate([rows[i] for i in sp.tx_slots])
                 rx[j] = lax.ppermute(tx, axis_name, perm=sp.perm)
         # 2) combine phase: all pairwise combines of this tick
         if impl == "pallas":
@@ -424,8 +426,8 @@ def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
                 sp = plan.steps[s]
                 if sp.n_adds:
                     rows = bucket_rows[j]
-                    jobs.append((jnp.stack([rows[i] for i in sp.add_src]),
-                                 _take(rx[j], sp.add_arr)))
+                    jobs.append(([rows[i] for i in sp.add_src],
+                                 [_row(rx[j], a, u) for a in sp.add_arr]))
                     owners.append((j, s))
             if jobs:        # ticks of recv-only steps have no combines
                 for (j, s), summed in zip(owners,
@@ -439,7 +441,7 @@ def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
                 rows = bucket_rows[j]
                 # read every resident before rebinding any slot: a fresh
                 # destination may reuse a slot another combine still reads
-                sums = [op(rows[src], rx[j][arr])
+                sums = [op(rows[src], _row(rx[j], arr, u))
                         for src, arr in zip(sp.add_src, sp.add_arr)]
                 for dst, v in zip(sp.add_dst, sums):
                     rows[dst] = v
@@ -448,7 +450,7 @@ def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
             sp = plan.steps[s]
             rows = bucket_rows[j]
             for slot, arr in zip(sp.recv_slots, sp.recv_arr):
-                rows[slot] = rx[j][arr]
+                rows[slot] = _row(rx[j], arr, u)
 
 
 # ---------------------------------------------------------------------------

@@ -75,7 +75,8 @@ def fused_combine(a: jnp.ndarray, b: jnp.ndarray, *,
         in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
                   pl.BlockSpec((rows, _LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(a2.shape, a.dtype),
+        out_shape=jax.ShapeDtypeStruct(a2.shape, a.dtype,
+                                       vma=jax.typeof(a2).vma),
         interpret=interpret,
     )(a2, b2)
     return out.reshape(-1)[:n]
@@ -99,7 +100,8 @@ def combine_n(stack: jnp.ndarray, *, accum_dtype=jnp.float32,
         grid=(grid,),
         in_specs=[pl.BlockSpec((k, rows, _LANES), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(s2.shape[1:], stack.dtype),
+        out_shape=jax.ShapeDtypeStruct(s2.shape[1:], stack.dtype,
+                                       vma=jax.typeof(s2).vma),
         interpret=interpret,
     )(s2)
     return out.reshape(-1)[:n]

@@ -21,9 +21,8 @@ import numpy as np
 
 
 def _axis_kw(n: int) -> dict:
-    from repro.compat import default_axis_types
-    at = default_axis_types(n)
-    return {} if at is None else {"axis_types": at}
+    from jax.sharding import AxisType
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

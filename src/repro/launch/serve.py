@@ -37,6 +37,8 @@ def main():
     args = ap.parse_args()
 
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
     from repro.configs import get_config, get_reduced
     from repro.launch.mesh import make_mesh, parallel_config_for

@@ -47,6 +47,8 @@ def main():
     args = ap.parse_args()
 
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.coordinator:
         jax.distributed.initialize(args.coordinator, args.num_processes,
                                    args.process_id)

@@ -156,8 +156,6 @@ def _tick_phase_fns(plan, active, axis_name, mesh):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     spec = P(axis_name, None, None, None)
     rx_spec = tuple(P(axis_name, None, None) for _ in active)
 
@@ -184,9 +182,9 @@ def _tick_phase_fns(plan, active, axis_name, mesh):
                     rx[jnp.asarray(sp.recv_arr)])
         return b[None]
 
-    send_fn = jax.jit(compat.shard_map(
+    send_fn = jax.jit(jax.shard_map(
         send, mesh=mesh, in_specs=spec, out_specs=rx_spec))
-    combine_fn = jax.jit(compat.shard_map(
+    combine_fn = jax.jit(jax.shard_map(
         combine, mesh=mesh, in_specs=(spec, rx_spec), out_specs=spec))
     return send_fn, combine_fn
 

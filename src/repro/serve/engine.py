@@ -26,9 +26,18 @@ measured tuning table attached (``tuning=True`` +
 ``REPRO_TUNING_CACHE``), the trace-time picks report
 ``source="measured"``; inspect them via :attr:`Engine.decode_choices`.
 
-Sampling is deterministic per ``(seed, request uid, token index)``
-(Gumbel-max over the logits), so outputs are bit-stable regardless of
-which slot a request lands on or what shares its batch.
+Sampling draws its randomness per ``(seed, request uid, token index)``
+(Gumbel-max over the logits), never per slot or batch.  The logits
+themselves are not promised bit-stable across batches: a request's rows
+are computed at batch and chunk shapes that depend on what else is being
+served, and the compiler may order a reduction differently at another
+shape (bf16 activations on the chip make that rounding coarse).  So
+where two tokens score within rounding of each other, a request may take
+a different token, and then a different continuation, beside other
+requests than alone.  The contract is checked against the model's plain
+full forward instead: every served token scores within a stated
+tolerance of the reference's best
+(:func:`repro.serve.reference.served_token_margins`).
 """
 from __future__ import annotations
 
@@ -48,6 +57,7 @@ from repro.obs.metrics import Histogram
 from repro.parallel.api import (ParallelConfig, decode_choice_log,
                                 reset_decode_choice_log)
 from repro.serve.kv import KVBlockManager
+from repro.serve.reference import gumbel_noise
 from repro.train.step import make_paged_serve_step
 
 _RECURRENT = ("rglru", "mlstm", "slstm")
@@ -242,7 +252,12 @@ class Engine:
             s.fresh = False
         table = np.concatenate([m.table for m in self.kv], axis=0)
         ctx = PageCtx(block_table=jnp.asarray(table),
-                      lengths=jnp.asarray(self.lengths),
+                      # a host copy: on the CPU backend the device array
+                      # may alias the numpy buffer (jnp.array's own copy
+                      # runs asynchronously, reading it late), and this
+                      # loop updates self.lengths in place while the
+                      # step may still be running
+                      lengths=jnp.asarray(self.lengths.copy()),
                       n_new=jnp.asarray(n_new),
                       reset=jnp.asarray(reset))
         prefill = bool((n_new > 1).any()) or any(
@@ -307,9 +322,7 @@ class Engine:
         admission order, or batch mates."""
         if self.temperature <= 0:
             return int(logits_row.argmax())
-        g = np.random.default_rng(
-            np.random.SeedSequence([self.seed, uid, step]))
-        gumbel = -np.log(-np.log(g.random(logits_row.shape[-1])))
+        gumbel = gumbel_noise(self.seed, uid, step, logits_row.shape[-1])
         return int((logits_row / self.temperature + gumbel).argmax())
 
     # ------------------------------------------------------------ stats

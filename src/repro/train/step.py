@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.allreduce import tree_reduce_scatter
 from repro.core.cost_model import Fabric, TPU_V5E_ICI
 from repro.models.config import ModelConfig
@@ -158,9 +158,8 @@ def _leaf_layers(params_shapes):
     path-based derivation.  The return aligns leaf-for-leaf with
     ``jax.tree.leaves(params_shapes)``.
     """
-    import jax.tree_util as jtu
     n_prefix = len(params_shapes.get("prefix", []))
-    flat, _ = jtu.tree_flatten_with_path(params_shapes)
+    flat, _ = jax.tree.flatten_with_path(params_shapes)
     layers = []
     for path, _leaf in flat:
         top = getattr(path[0], "key", None)

@@ -2,11 +2,11 @@
 
 One JSON file holds every measurement this machine has ever taken, grouped
 under a *backend fingerprint* (platform, device kind/count, jax version,
-package version).  A measurement taken on 8 forced-host CPU devices under
-jax 0.4.37 says nothing about a v5e pod under jax 0.6, so lookups only see
-entries whose fingerprint matches the running backend exactly; stale
-entries are kept on disk (they become live again when the matching backend
-returns) but never consulted.
+package version).  A measurement taken on 8 forced-host CPU devices says
+nothing about a v5e pod, nor one taken under another jax release, so
+lookups only see entries whose fingerprint matches the running backend
+exactly; stale entries are kept on disk (they become live again when the
+matching backend returns) but never consulted.
 
 File handling rules:
 
@@ -169,7 +169,7 @@ class TuningCache:
     """In-memory view of the on-disk tuning table.
 
     >>> import os, tempfile
-    >>> fp = Fingerprint("cpu", "host", 8, "0.4.37", "1.0.0")
+    >>> fp = Fingerprint("cpu", "host", 8, "0.9.0", "1.0.0")
     >>> cache = TuningCache()
     >>> cache.record(fp, Measurement(8, 1024, "generalized", 2, 1, 42.0))
     >>> cache.n_measurements

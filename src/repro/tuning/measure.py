@@ -160,7 +160,7 @@ def run_tuning(
     from jax import lax
     from jax.sharding import PartitionSpec as P_
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.allreduce import allreduce_flat
 
     n = len(jax.devices())

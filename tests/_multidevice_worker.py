@@ -15,7 +15,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.core.allreduce import (all_gather_flat, all_to_all_flat,  # noqa: E402
                                   allreduce_flat, allreduce_tree,
                                   hierarchical_allreduce,
@@ -298,8 +298,10 @@ def check_execplan():
                 for d in range(n):
                     assert (out[d] == want).all(), \
                         (m, sched.kind, sched.r, nb, d)
-    # combine_n-routed steps (check_vma=False: old-JAX replication
-    # checkers have no pallas rule) == chained jnp.add, bit for bit.
+    # combine_n-routed steps == chained jnp.add, bit for bit.
+    # check_vma=False: the kernel's interpret mode (this CPU path) does
+    # not trace under the checker; the Mosaic path does (see
+    # tests/test_tpu_compile.py and chip_smoke.py --chips 4).
     # The latency-optimal schedule batches several combines per tick into
     # one kernel call; ring additionally covers add-free (recv-only)
     # ticks in its all-gather half -- pallas must skip those, not crash.
@@ -403,7 +405,7 @@ def check_maxreduce():
         assert (np.asarray(g(x)) == refs["max"][None]).all()
 
     # pallas-routed max == elementwise max, bit for bit (check_vma=False:
-    # old-JAX replication checkers have no pallas rule)
+    # interpret mode does not trace under the checker, as above)
     x = rng.integers(-1000, 1000, (n, 257)).astype(np.int32)
     sched = build_generalized(n, max_r(n))
     outs = {}

@@ -27,8 +27,8 @@ OC = OptConfig(lr=1e-3, warmup_steps=0, total_steps=100, grad_clip=None)
 # recurrence chains bf16 matmul outputs through an exponential-gated
 # cumulative scan, so the dp=2 batch split (different device boundaries
 # -> different reassociation of the same bf16 sums) compounds through the
-# sequence dimension instead of averaging out; on jax 0.4.37's CPU
-# backend the resulting loss drift is ~0.11 (absolute, at loss ~6.05)
+# sequence dimension instead of averaging out; on XLA's CPU backend the
+# resulting loss drift has been measured at ~0.11 (absolute, at loss ~6.05)
 # while every attention arch stays < 5e-3.  The updated-parameter check
 # below stays at the tight default -- it would catch a genuine gradient
 # sync bug that a loss-level gate this loose could hide.

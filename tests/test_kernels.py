@@ -88,6 +88,41 @@ def _attn_case(B, Hq, Hkv, Sq, Skv, D, causal, window, dtype,
                                rtol=rtol, atol=atol)
 
 
+@pytest.mark.parametrize("dtype,rows", [(jnp.float32, 128),
+                                        (jnp.bfloat16, 192)])
+def test_rmsnorm_block_rows_fit_vmem_at_granite_width(dtype, rows):
+    """The default row block is sized from width and dtype: a multiple of
+    8 whose tiles fit the VMEM budget (the fixed 256 rows did not fit
+    at d = 4096 in fp32)."""
+    from repro.kernels.rmsnorm import _VMEM_BUDGET, block_rows_for
+    got = block_rows_for(4096, dtype)
+    assert got == rows and got % 8 == 0
+    assert got * 4096 * (4 * jnp.dtype(dtype).itemsize + 8) <= _VMEM_BUDGET
+    assert block_rows_for(64, dtype) == 256      # narrow rows: the cap
+    assert block_rows_for(1 << 20, dtype) == 8   # very wide: the floor
+
+
+@pytest.mark.parametrize("call", ["kv_valid", "q_positions", "return_lse",
+                                  "combine_2d"])
+def test_pallas_ops_refuse_what_the_kernel_cannot_do(call):
+    """Asked for the Pallas kernel where it has no such mode, the ops
+    layer raises instead of quietly running the XLA reference."""
+    from repro.kernels import ops
+    q = jnp.zeros((1, 2, 1, 16), jnp.float32)
+    kv = jnp.zeros((1, 2, 8, 16), jnp.float32)
+    with pytest.raises(ValueError):
+        if call == "kv_valid":
+            ops.attention(q, kv, kv, causal=False, impl="pallas",
+                          kv_valid=jnp.array([4]))
+        elif call == "q_positions":
+            ops.attention(q, kv, kv, impl="pallas",
+                          q_positions=jnp.array([[3]]))
+        elif call == "return_lse":
+            ops.attention(q, kv, kv, impl="pallas", return_lse=True)
+        else:
+            ops.combine(jnp.zeros((2, 8)), jnp.zeros((2, 8)))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_causal_selfattn(dtype):
     _attn_case(2, 4, 4, 64, 64, 32, True, None, dtype)

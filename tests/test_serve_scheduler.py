@@ -9,7 +9,8 @@ checking the scheduler invariants:
   * token conservation: every request gets exactly ``max_new_tokens``
     and ``Engine.stats()`` counts them exactly, under slot recycling;
   * admission is strict FIFO under equal priority;
-  * greedy continuous-batch output is bit-identical to a B=1 solo run.
+  * every greedy token, batched or solo, scores within ``LOGIT_TOL`` of
+    the best token under the model's plain full forward.
 
 Runs under real ``hypothesis`` when installed and under the bundled
 fallback engine (``tests/_hypothesis_compat``) otherwise.
@@ -23,12 +24,21 @@ from repro.models.model import init_params
 from repro.parallel.api import ParallelConfig
 from repro.launch.mesh import make_mesh
 from repro.serve.engine import Engine, Request
+from repro.serve.reference import served_token_margins
 
 TINY = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                    n_heads=4, n_kv_heads=2, d_ff=160, vocab=256,
                    head_dim=16, act="swiglu")
 MAX_LEN = 32
 CHUNK = 8
+# Engine and reference run the same bf16 model at different shapes
+# (paged chunked prefill in a batch vs one full pass), so activations
+# round differently.  bf16 keeps 8 mantissa bits: one rounding moves a
+# unit-scale value by at most 2^-8 ~ 0.004, and the few roundings that
+# differ over TINY's two layers move its unit-scale logits well under
+# 0.05.  A token the reference does not favour sits a median ~2.7 below
+# its best, so this tolerance still rejects a wrong choice.
+LOGIT_TOL = 0.05
 
 _CTX = {}
 
@@ -134,17 +144,24 @@ def test_scheduler_invariants(batch_slots, tight, data):
 @settings(max_examples=6, deadline=None)
 @given(batch_slots=st.integers(2, 3), data=st.data())
 def test_scheduler_greedy_matches_solo(batch_slots, data):
+    """Batched and solo greedy runs of each request both pick, at every
+    step, a token within LOGIT_TOL of the reference's best (they may
+    still differ from each other where two tokens tie within rounding)."""
     n_req = data.draw(st.integers(2, 4))
     lengths = [data.draw(st.integers(1, 16)) for _ in range(n_req)]
     seed = data.draw(st.integers(0, 10**6))
     reqs = _requests(seed, lengths, [4] * n_req)
     _engine(batch_slots).generate(reqs)
     solo = _engine(1)
+    params = _ctx()["params"]
     for r in reqs:
         r2 = Request(prompt=r.prompt, max_new_tokens=r.max_new_tokens)
         solo.generate([r2])
-        assert r2.out_tokens == r.out_tokens, \
-            (len(r.prompt), r.out_tokens, r2.out_tokens)
+        for got in (r, r2):
+            margins = served_token_margins(TINY, params, got.prompt,
+                                           got.out_tokens)
+            assert margins.max() <= LOGIT_TOL, \
+                (len(r.prompt), r.out_tokens, r2.out_tokens, margins)
 
 
 def test_stats_wellformed_before_any_request_completes():

@@ -20,6 +20,14 @@ from repro.train.optimizer import OptConfig, init_opt_state, lr_at
 TINY = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                    n_heads=4, n_kv_heads=2, d_ff=160, vocab=256,
                    head_dim=16, act="swiglu")
+# Served tokens are checked against the model's plain full forward, not
+# for bit equality across batches (see repro.serve.reference).  Engine
+# and reference run the same bf16 model at different shapes, and one
+# bf16 rounding moves a unit-scale value by at most 2^-8 ~ 0.004; the
+# few that differ over TINY's two layers move its unit-scale logits
+# well under 0.05, while a token the reference does not favour sits a
+# median ~2.7 below its best.
+LOGIT_TOL = 0.05
 
 
 # ------------------------------------------------------------------ data
@@ -282,9 +290,12 @@ def test_engine_mixed_length_prompts_match_solo():
 
 
 def test_engine_sampling_deterministic_per_request():
-    """Gumbel-max sampling is keyed by (seed, uid, step): outputs are
-    bit-stable regardless of slot count / admission order / batch mates."""
+    """Gumbel-max sampling is keyed by (seed, uid, step), never by slot,
+    admission order or batch mates: under two slot counts and submit
+    orders, every sampled token scores within LOGIT_TOL of the best
+    under the full-forward reference plus that same keyed draw."""
     from repro.serve.engine import Engine, Request
+    from repro.serve.reference import served_token_margins
     mesh = make_mesh((1, 1), ("data", "model"))
     pc = ParallelConfig(dp=1, tp=1)
     params, _ = init_params(TINY, pc, jax.random.PRNGKey(0))
@@ -295,13 +306,18 @@ def test_engine_sampling_deterministic_per_request():
         reqs = [Request(prompt=np.arange(4, dtype=np.int32) + i,
                         max_new_tokens=4, uid=i) for i in range(4)]
         eng.generate([reqs[i] for i in order])
-        return {r.uid: r.out_tokens for r in reqs}
+        return reqs
 
-    a = serve(2, [0, 1, 2, 3])
-    b = serve(3, [2, 0, 3, 1])   # different slots AND submit order
-    assert a == b
-    # and distinct requests don't all sample identically by accident
-    assert len({tuple(v) for v in a.values()}) > 1
+    for reqs in (serve(2, [0, 1, 2, 3]),
+                 serve(3, [2, 0, 3, 1])):  # different slots AND order
+        for r in reqs:
+            margins = served_token_margins(TINY, params, r.prompt,
+                                           r.out_tokens, temperature=0.7,
+                                           seed=11, uid=r.uid)
+            assert margins.max() <= LOGIT_TOL, (r.uid, r.out_tokens,
+                                                margins)
+        # distinct requests don't all sample identically by accident
+        assert len({tuple(r.out_tokens) for r in reqs}) > 1
 
 
 # ------------------------------------------------------------ elastic
@@ -405,3 +421,30 @@ def test_serve_engine_tp_dp_8dev():
                          timeout=600)
     assert res.returncode == 0, f"worker failed:\n{res.stdout}\n{res.stderr}"
     assert "ok serve" in res.stdout, res.stdout
+
+
+# ------------------------------------------------------------ compile cache
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir_is_fixed_or_from_env(monkeypatch, tmp_path,
+                                                from_env):
+    """Without JAX_COMPILATION_CACHE_DIR the cache sits at one fixed,
+    git-ignored path of the checkout; with it, JAX's own setting stands
+    and the helper sets no other directory."""
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+            jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        else:
+            monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+            got = compile_cache.enable_compile_cache()
+            root = os.path.dirname(os.path.dirname(__file__))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            with open(os.path.join(root, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -264,7 +264,7 @@ def test_tuned_choice_executes_correctly(tuned_env, tmp_path):
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.allreduce import allreduce_tree
 from repro.core.autotune import choose
 
