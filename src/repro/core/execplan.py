@@ -44,21 +44,25 @@ reduces one flat gradient tensor through a single (possibly
 multi-bucket) :func:`execute`, while the backward-overlapped path
 (:func:`repro.parallel.api.attach_overlap_sync`) dispatches one
 ``execute`` per reverse-layer gradient bucket *as the backward pass
-produces it*, tagging each dispatch (``tag="grad_bucket<k>"``) so the
-trace timeline and the exposed-comm roofline
+produces it*, tagging each dispatch (``tag="grad_bucket<k>"``) so a
+profile and the exposed-comm roofline
 (:func:`repro.core.cost_model.overlap_tick_costs`) can line the
 per-bucket dispatches up against backward compute.
+
+The replay's ops carry named scopes, so a device profile attributes
+them at run time: ``execplan.<kind>`` around the replay (inside the
+caller's ``tag``, where given), ``tick<t>`` around each tick and
+``combine`` around each tick's combines.
 """
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
-
-from repro.obs import trace as obs_trace
 
 from .monoid import CombineLike, Monoid, resolve_combine
 from .schedule import Schedule, ShapeError, ragged_offsets, ragged_sizes
@@ -370,12 +374,14 @@ def execute(plan: ExecPlan, bucket_rows: Sequence[List], axis_name, *,
     affine bookends of mean / premul_sum are the caller's job (they act
     on the whole message, not per step).
 
-    ``tag`` is an optional caller-supplied label recorded on the
-    ``execplan.execute`` trace span -- the backward-overlapped gradient
-    sync (:func:`repro.parallel.api.dp_grad_allreduce`) tags each
-    gradient bucket (e.g. ``"grad_bucket3"``) so per-bucket dispatches
-    are identifiable in the trace timeline.
+    ``tag`` is an optional caller-supplied label, a named scope around
+    the replay's ops -- the backward-overlapped gradient sync
+    (:func:`repro.parallel.api.dp_grad_allreduce`) tags each gradient
+    bucket (e.g. ``"grad_bucket3"``) so per-bucket dispatches are
+    identifiable in a device profile.
     """
+    import jax
+
     from repro.kernels import ops as kernel_ops
 
     monoid, impl = resolve_combine(combine)
@@ -386,40 +392,46 @@ def execute(plan: ExecPlan, bucket_rows: Sequence[List], axis_name, *,
         raise ValueError(f"monoid {monoid.name!r} has no fused Pallas "
                          f"kernel; use the elementwise path")
     bucket_rows = [list(rows) for rows in bucket_rows]
-    B = len(bucket_rows)
-    S = plan.n_steps
-    # Trace-time span only: inside shard_map/jit this loop *builds* the
-    # program, it does not run it, so the span measures staging cost.
-    # Per-tick runtime timelines come from the blocking replay in
-    # repro.obs.instrument, which follows the same tick_structure().
-    ticks = tick_structure(plan, B)
-    attrs = {} if tag is None else {"tag": tag}
-    with obs_trace.span("execplan.execute", cat="trace", kind=plan.kind,
-                        P=plan.P, n_steps=S, n_buckets=B,
-                        n_ticks=len(ticks), **attrs):
+    ticks = tick_structure(plan, len(bucket_rows))
+    with jax.named_scope(tag) if tag else nullcontext(), \
+            jax.named_scope(f"execplan.{plan.kind}"):
         _execute_ticks(plan, bucket_rows, ticks, axis_name, monoid, impl)
     return bucket_rows
 
 
 def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
                    axis_name, monoid: Monoid, impl: str) -> None:
-    """Stage the tick loop in place over ``bucket_rows`` (see execute)."""
-    import jax.numpy as jnp
-    from jax import lax
+    """Stage the tick loop in place over ``bucket_rows`` (see execute),
+    each tick under its ``tick<t>`` scope."""
+    import jax
 
     # every row has the bucket width u; multi-row buffers stay flat
     u = next(r.shape[0] for rows in bucket_rows for r in rows
              if r is not None)
-    for active in ticks:
-        # 1) issue phase: stage every active bucket's communication
-        rx = {}
-        for j, s in active:
-            sp = plan.steps[s]
-            if sp.n_tx:
-                rows = bucket_rows[j]
-                tx = jnp.concatenate([rows[i] for i in sp.tx_slots])
-                rx[j] = lax.ppermute(tx, axis_name, perm=sp.perm)
-        # 2) combine phase: all pairwise combines of this tick
+    for t, active in enumerate(ticks):
+        with jax.named_scope(f"tick{t}"):
+            _execute_tick(plan, bucket_rows, active, axis_name, monoid,
+                          impl, u)
+
+
+def _execute_tick(plan: ExecPlan, bucket_rows: List[List], active,
+                  axis_name, monoid: Monoid, impl: str, u: int) -> None:
+    """Stage one tick: every active bucket's send, then its combines
+    (the ``combine`` scope), then its received rows land."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    # 1) issue phase: stage every active bucket's communication
+    rx = {}
+    for j, s in active:
+        sp = plan.steps[s]
+        if sp.n_tx:
+            rows = bucket_rows[j]
+            tx = jnp.concatenate([rows[i] for i in sp.tx_slots])
+            rx[j] = lax.ppermute(tx, axis_name, perm=sp.perm)
+    # 2) combine phase: all pairwise combines of this tick
+    with jax.named_scope("combine"):
         if impl == "pallas":
             jobs, owners = [], []
             for j, s in active:
@@ -445,12 +457,12 @@ def _execute_ticks(plan: ExecPlan, bucket_rows: List[List], ticks,
                         for src, arr in zip(sp.add_src, sp.add_arr)]
                 for dst, v in zip(sp.add_dst, sums):
                     rows[dst] = v
-        # 3) land received rows in their freed slots
-        for j, s in active:
-            sp = plan.steps[s]
-            rows = bucket_rows[j]
-            for slot, arr in zip(sp.recv_slots, sp.recv_arr):
-                rows[slot] = _row(rx[j], arr, u)
+    # 3) land received rows in their freed slots
+    for j, s in active:
+        sp = plan.steps[s]
+        rows = bucket_rows[j]
+        for slot, arr in zip(sp.recv_slots, sp.recv_arr):
+            rows[slot] = _row(rx[j], arr, u)
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import ops as kops
-from repro.models.layers import COMPUTE_DTYPE, dense, rope
+from repro.models.layers import COMPUTE_DTYPE, cast_weight, dense, rope
 from repro.parallel.api import ParallelConfig, tp_rank
 
 
@@ -155,7 +155,7 @@ def attention_block(p, xg, cfg, pc: ParallelConfig, *,
             impl=attn_impl)
         o = o.swapaxes(1, 2).reshape(B, S, -1)
         out = jax.lax.dot_general(
-            o, p["wo"].astype(o.dtype), (((2,), (0,)), ((), ())),
+            o, cast_weight(p["wo"], o.dtype), (((2,), (0,)), ((), ())),
             preferred_element_type=o.dtype)
         return out, cache
     if cache is not None and seq_shard:
@@ -166,7 +166,7 @@ def attention_block(p, xg, cfg, pc: ParallelConfig, *,
         span = local_q_heads(cfg, pc) * cfg.hd
         o = lax.dynamic_slice_in_dim(o_full, tp_rank(pc) * span, span, 2)
         out = jax.lax.dot_general(
-            o, p["wo"].astype(o.dtype), (((2,), (0,)), ((), ())),
+            o, cast_weight(p["wo"], o.dtype), (((2,), (0,)), ((), ())),
             preferred_element_type=o.dtype)
         return out, cache
     q, k, v = qkv_project(p, xg, cfg, pc)
@@ -191,7 +191,7 @@ def attention_block(p, xg, cfg, pc: ParallelConfig, *,
             impl=attn_impl)
     o = o.swapaxes(1, 2).reshape(B, S, -1)           # (B, S, Hl*hd)
     out = jax.lax.dot_general(
-        o, p["wo"].astype(o.dtype), (((2,), (0,)), ((), ())),
+        o, cast_weight(p["wo"], o.dtype), (((2,), (0,)), ((), ())),
         preferred_element_type=o.dtype)
     return out, cache
 

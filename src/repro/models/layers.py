@@ -30,6 +30,14 @@ def norm_apply(p, x, *, kind: str = "rmsnorm", eps: float = 1e-5,
     return (y * p["w"] + p["b"]).astype(x.dtype)
 
 
+def cast_weight(w, dtype):
+    """``w`` in ``dtype``, under the ``weight_cast`` named scope, so a
+    profile can tell the per-call cast of fp32 master weights from the
+    matmul that reads them."""
+    with jax.named_scope("weight_cast"):
+        return w.astype(dtype)
+
+
 def dense(x, w):
     """Local matmul in compute dtype.
 
@@ -38,7 +46,7 @@ def dense(x, w):
     both the live-buffer footprint and the bytes of any TP partial-sum
     reduce that follows."""
     return jax.lax.dot_general(
-        x, w.astype(x.dtype),
+        x, cast_weight(w, x.dtype),
         (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=x.dtype)
 
@@ -57,7 +65,7 @@ def embed_tokens(p, tokens, cfg, pc: ParallelConfig, *, sp: bool):
     """
     # cast the (V/tp, d) table once; gathering from the fp32 master would
     # materialize a fp32 (B, S, d) tensor
-    table = p["w"].astype(COMPUTE_DTYPE)             # (V/tp, d) local
+    table = cast_weight(p["w"], COMPUTE_DTYPE)       # (V/tp, d) local
     vshard = table.shape[0]
     if vshard == cfg.vocab:
         # replicated table (vocab % tp != 0): full values, slice for SP
@@ -203,7 +211,7 @@ def mlp_apply(p, x, cfg, pc: ParallelConfig, *, act: Optional[str] = None):
     else:
         h = jax.nn.gelu(dense(x, p["w1"]))
     return jax.lax.dot_general(
-        h, p["w2"].astype(h.dtype), (((h.ndim - 1,), (0,)), ((), ())),
+        h, cast_weight(p["w2"], h.dtype), (((h.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=h.dtype)
 
 

@@ -26,8 +26,8 @@ from repro.models.attention import (PageCtx, attention_block,
                                     attn_replicated, init_cache,
                                     init_paged_pool, kv_replicated)
 from repro.models.config import ModelConfig
-from repro.models.layers import (COMPUTE_DTYPE, embed_tokens, mlp_apply,
-                                 norm_apply, vocab_parallel_ce)
+from repro.models.layers import (COMPUTE_DTYPE, cast_weight, embed_tokens,
+                                 mlp_apply, norm_apply, vocab_parallel_ce)
 from repro.parallel.api import (ParallelConfig, ParamSpec, choose_fsdp_dim,
                                 fsdp_gather_tree, seq_all_gather,
                                 seq_reduce_scatter, tp_decode_all_gather,
@@ -342,10 +342,11 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, pc: ParallelConfig, *,
             lambda old, f: jnp.where(_row_mask(paged.reset, old.ndim),
                                      f, old), cache, fresh)
     if kind in ("attn", "local_attn"):
-        mix, new_cache = attention_block(
-            p["attn"], hg, cfg, pc, window=window, positions=positions,
-            cache=cache, rolling=rolling, seq_shard=seq_shard,
-            paged=paged, attn_impl=attn_impl)
+        with jax.named_scope("attn"):
+            mix, new_cache = attention_block(
+                p["attn"], hg, cfg, pc, window=window, positions=positions,
+                cache=cache, rolling=rolling, seq_shard=seq_shard,
+                paged=paged, attn_impl=attn_impl)
     elif kind == "rglru":
         mix, new_cache = rec.rglru_block(p["rnn"], hg, cfg, pc, state=cache)
     elif kind == "mlstm":
@@ -373,11 +374,14 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, pc: ParallelConfig, *,
     else:
         out = seq_reduce_scatter(mix, pc) if sp else _psum(mix, pc)
 
+    def mlp(h):
+        with jax.named_scope("mlp"):
+            if moe_layer and cfg.moe is not None:
+                return moe_lib.moe_apply(p["mlp"], h, cfg, pc)
+            return mlp_apply(p["mlp"], h, cfg, pc), jnp.float32(0.0)
+
     if cfg.parallel_residual and _block_has_mlp(cfg, kind):
-        if moe_layer and cfg.moe is not None:
-            mo, aux = moe_lib.moe_apply(p["mlp"], hg, cfg, pc)
-        else:
-            mo = mlp_apply(p["mlp"], hg, cfg, pc)
+        mo, aux = mlp(hg)
         mo = seq_reduce_scatter(mo, pc) if sp else _psum(mo, pc)
         return x + out + mo, new_cache, aux
 
@@ -385,10 +389,7 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, pc: ParallelConfig, *,
     if _block_has_mlp(cfg, kind):
         h2 = norm_apply(p["ln2"], x, kind=cfg.norm, eps=cfg.norm_eps)
         hg2 = seq_all_gather(h2, pc) if sp else h2
-        if moe_layer and cfg.moe is not None:
-            mo, aux = moe_lib.moe_apply(p["mlp"], hg2, cfg, pc)
-        else:
-            mo = mlp_apply(p["mlp"], hg2, cfg, pc)
+        mo, aux = mlp(hg2)
         x = x + (seq_reduce_scatter(mo, pc) if sp else _psum(mo, pc))
     return x, new_cache, aux
 
@@ -534,12 +535,34 @@ def loss_and_metrics(params, specs, batch, cfg: ModelConfig,
             axis=1)
     head = params["head"] if not cfg.tie_embeddings else {
         "w": params["embed"]["w"].T}
-    total, count = vocab_parallel_ce(head, hidden, labels, cfg, pc, sp=True)
+    with jax.named_scope("head"):
+        total, count = vocab_parallel_ce(head, hidden, labels, cfg, pc,
+                                         sp=True)
     loss = total / jnp.maximum(count, 1) + aux
     return loss, (total, count, aux)
 
 
 # ---------------------------------------------------------------- serving
+def _serve_params(params, specs, pc: ParallelConfig):
+    """A serve step's parameters: the non-scanned ones FSDP-gathered once,
+    and every block's attention and MLP matrices in the compute dtype,
+    cast once per step under the ``weight_cast`` scope.  Each use casts
+    them so anyway (:func:`~repro.models.layers.cast_weight`), so the
+    values are the same; cast here, before the layer loop, the cast keeps
+    its scope in the compiled program, where XLA would hoist the
+    per-layer casts out of the loop itself and drop their metadata."""
+    top = {k: v for k, v in params.items() if k != "cycles"}
+    top_specs = {k: v for k, v in specs.items() if k != "cycles"}
+    top = fsdp_gather_tree(top, top_specs, pc)
+
+    def cast(block):
+        return {k: jax.tree.map(lambda w: cast_weight(w, COMPUTE_DTYPE), v)
+                if k in ("attn", "mlp") else v for k, v in block.items()}
+
+    return dict(top, prefix=[cast(bp) for bp in top["prefix"]],
+                cycles={g: cast(bp) for g, bp in params["cycles"].items()})
+
+
 def init_caches(cfg: ModelConfig, pc: ParallelConfig, batch_local: int,
                 max_len: int, *, rolling: bool = False,
                 seq_shard: bool = False):
@@ -613,25 +636,23 @@ def paged_decode_step(params, specs, tokens, caches, paged: PageCtx,
     KV writes and attention masks are all per-row via ``paged``; the
     final vocab gather runs on the decode-path collectives
     (:func:`repro.parallel.api.tp_decode_all_gather`)."""
-    top = {k: v for k, v in params.items() if k != "cycles"}
-    top_specs = {k: v for k, v in specs.items() if k != "cycles"}
-    top = fsdp_gather_tree(top, top_specs, pc)
-    params = dict(top, cycles=params["cycles"])
+    params = _serve_params(params, specs, pc)
 
     hidden, new_caches, _ = forward(params, specs, {"tokens": tokens}, cfg,
                                     pc, sp=False, caches=caches, paged=paged,
                                     attn_impl=attn_impl)
-    # row b's next-token logits live at its last valid position
-    last = jnp.clip(paged.n_new - 1, 0, hidden.shape[1] - 1)
-    hidden = jnp.take_along_axis(hidden, last[:, None, None], axis=1)
     head = params["head"] if not cfg.tie_embeddings else {
         "w": params["embed"]["w"].T}
-    logits = jax.lax.dot_general(
-        hidden, head["w"].astype(hidden.dtype),
-        (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (B, 1, V/tp)
-    if pc.tp > 1 and logits.shape[-1] != cfg.vocab:
-        logits = tp_decode_all_gather(logits, pc, axis=2)
+    with jax.named_scope("head"):
+        # row b's next-token logits live at its last valid position
+        last = jnp.clip(paged.n_new - 1, 0, hidden.shape[1] - 1)
+        hidden = jnp.take_along_axis(hidden, last[:, None, None], axis=1)
+        logits = jax.lax.dot_general(
+            hidden, cast_weight(head["w"], hidden.dtype),
+            (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (B, 1, V/tp)
+        if pc.tp > 1 and logits.shape[-1] != cfg.vocab:
+            logits = tp_decode_all_gather(logits, pc, axis=2)
     return logits, new_caches
 
 
@@ -647,10 +668,7 @@ def decode_step(params, specs, tokens, caches, pos0, cfg: ModelConfig,
     positions against a 256k vocab would materialize a 67 GB logits
     tensor nobody reads.
     """
-    top = {k: v for k, v in params.items() if k != "cycles"}
-    top_specs = {k: v for k, v in specs.items() if k != "cycles"}
-    top = fsdp_gather_tree(top, top_specs, pc)
-    params = dict(top, cycles=params["cycles"])
+    params = _serve_params(params, specs, pc)
 
     batch = {"tokens": tokens}
     hidden, new_caches, _ = forward(params, specs, batch, cfg, pc, sp=False,
@@ -659,12 +677,13 @@ def decode_step(params, specs, tokens, caches, pos0, cfg: ModelConfig,
                                     attn_impl=attn_impl)
     head = params["head"] if not cfg.tie_embeddings else {
         "w": params["embed"]["w"].T}
-    if hidden.shape[1] > logits_len:
-        hidden = hidden[:, -logits_len:, :]
-    logits = jax.lax.dot_general(
-        hidden, head["w"].astype(hidden.dtype),
-        (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (B, L, V/tp)
-    if pc.tp > 1 and logits.shape[-1] != cfg.vocab:
-        logits = lax.all_gather(logits, pc.tp_axis, axis=2, tiled=True)
+    with jax.named_scope("head"):
+        if hidden.shape[1] > logits_len:
+            hidden = hidden[:, -logits_len:, :]
+        logits = jax.lax.dot_general(
+            hidden, cast_weight(head["w"], hidden.dtype),
+            (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (B, L, V/tp)
+        if pc.tp > 1 and logits.shape[-1] != cfg.vocab:
+            logits = lax.all_gather(logits, pc.tp_axis, axis=2, tiled=True)
     return logits, new_caches

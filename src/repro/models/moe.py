@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.allreduce import all_to_all_flat
-from repro.models.layers import dense
+from repro.models.layers import cast_weight, dense
 from repro.parallel.api import ParallelConfig
 
 
@@ -52,7 +52,7 @@ def route(p_router, x, cfg_moe):
     Returns (expert_idx (T,k), probs (T,k), aux_loss scalar).
     """
     logits = jax.lax.dot_general(
-        x, p_router["w"].astype(x.dtype), (((1,), (0,)), ((), ())),
+        x, cast_weight(p_router["w"], x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (T, E) f32
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = lax.top_k(probs, cfg_moe.top_k)   # (T, k)
@@ -103,7 +103,7 @@ def experts_apply(p, xq, cfg, act: str):
         u = dense(x_e, w3)
         h = (jax.nn.silu(g) if act == "swiglu" else jax.nn.gelu(g)) * u
         return jax.lax.dot_general(
-            h, w2.astype(h.dtype), (((1,), (0,)), ((), ())),
+            h, cast_weight(w2, h.dtype), (((1,), (0,)), ((), ())),
             preferred_element_type=h.dtype)
     return jax.vmap(one)(xq, p["w1"], p["w3"], p["w2"])
 

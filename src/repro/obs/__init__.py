@@ -5,9 +5,11 @@ oracles, conformance harness) and *time* itself end to end (tuning
 grid, executor bench), but until this package it could not say where a
 schedule's time goes.  ``repro.obs`` adds the missing instrumentation:
 
-* :mod:`repro.obs.trace`    -- span/counter recorder with Chrome-trace
-  (Perfetto-loadable) JSON export; a process-global tracer that is a
-  near-zero-cost no-op until enabled;
+* :mod:`repro.obs.trace`    -- one span/counter recorder with two
+  sinks: Chrome-trace (Perfetto-loadable) JSON export, switched on with
+  ``enable()``, and the JAX profiler's host plane, on whenever a
+  ``jax.profiler`` session runs; a near-zero-cost no-op while neither
+  is on;
 * :mod:`repro.obs.metrics`  -- structured counters and histograms
   (bytes moved, combine FLOPs, request latency p50/p99) with a JSON
   snapshot format committed under ``results/``;

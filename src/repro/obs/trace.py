@@ -1,16 +1,30 @@
-"""Span/counter recorder with Chrome-trace (Perfetto-loadable) export.
+"""Span/counter recorder with two sinks: Chrome-trace (Perfetto-loadable)
+JSON export, and the JAX profiler's own trace.
 
 One process-global :class:`Tracer` collects *complete* span events
 (``ph: "X"``: name, timestamp, duration, process, thread) and counter
 samples (``ph: "C"``), and serializes them to the Chrome trace-event
 JSON format that ``ui.perfetto.dev`` / ``chrome://tracing`` load
-directly.  Design constraints, in order:
+directly.  That sink is switched on with :func:`enable`.
 
-1. **Disabled means free.**  The default tracer is disabled; the
-   module-level :func:`span` returns a shared no-op context manager
-   without allocating, so instrumentation sites sprinkled through hot
-   dispatch paths cost one attribute check (<2% on the executor bench,
-   gated by the benchmark's ``trace_off_overhead`` figure).
+The second sink needs no switch: while a JAX profiler session runs
+(``jax.profiler.trace(dir)``, ``start_trace``), every span is also a
+``jax.profiler.TraceAnnotation`` with its args as the event's stats, a
+counter becomes an arg of the innermost open span of its thread, and
+an instant a zero-length event.  They land on the profile's host plane,
+on the same clock as the device's operations.
+
+Design constraints, in order:
+
+1. **Disabled means free.**  With neither sink on, the module-level
+   :func:`span` returns a shared no-op context manager without
+   allocating, so instrumentation sites sprinkled through hot dispatch
+   paths cost one attribute check and one profiler-session check (<2%
+   on the executor bench, gated by the benchmark's
+   ``trace_off_overhead`` figure).  Callers whose span args cost work
+   compute them only when the span is live (``sp is not _NULL_SPAN``).
+   Importing this module imports no jax: the profiler's session check
+   is looked up once ``jax`` is imported, and cached.
 2. **Thread-safe nesting.**  Spans nest per thread (each thread has its
    own open-span stack); the event list append is lock-protected, so
    worker threads (async checkpointer, data prefetch) can trace freely.
@@ -33,9 +47,10 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 
 class _NullSpan:
@@ -56,31 +71,88 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+# ---------------------------------------------------------------------------
+#  the profiler sink
+# ---------------------------------------------------------------------------
+
+_annotation: Optional[Callable] = None    # jax.profiler.TraceAnnotation
+_session: Optional[Callable[[], bool]] = None   # its is_enabled
+_open = threading.local()     # per thread: the open spans that have an
+#                               annotation, innermost last
+
+
+def profiling() -> bool:
+    """True while a JAX profiler session runs in this process.  Always
+    False until ``jax`` has been imported (by anyone); the check is
+    then resolved once and cached."""
+    global _annotation, _session
+    if _session is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+        _annotation, _session = TraceAnnotation, TraceAnnotation.is_enabled
+    return _session()
+
+
+def _stat(v):
+    """A profiler stat value: numbers and strings as they are, anything
+    else as its text."""
+    return v if isinstance(v, (str, int, float)) else str(v)
+
+
+def _open_spans() -> list:
+    st = getattr(_open, "stack", None)
+    if st is None:
+        st = _open.stack = []
+    return st
+
+
 class _Span:
-    """One open span; appended to the tracer's event list on exit."""
+    """One open span: appended to the Chrome tracer's event list on exit
+    (``tracer`` None: that sink is off), and, with ``annotate``, open as
+    a profiler annotation meanwhile."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotate",
+                 "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: dict, annotate: bool):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._annotate = annotate
+        self._ann = None
 
     def set(self, **args) -> "_Span":
         """Attach result metadata discovered while the span is open."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**{k: _stat(v) for k, v in args.items()})
         return self
 
     def __enter__(self):
-        self._t0 = self._tracer._now_us()
-        self._tracer._push(self)
+        if self._annotate:
+            self._ann = _annotation(
+                self.name, **{k: _stat(v) for k, v in self.args.items()})
+            self._ann.__enter__()
+            _open_spans().append(self)
+        if self._tracer is not None:
+            self._t0 = self._tracer._now_us()
+            self._tracer._push(self)
         return self
 
     def __exit__(self, *exc):
-        t1 = self._tracer._now_us()
-        self._tracer._pop(self, self._t0, t1 - self._t0)
+        if self._tracer is not None:
+            t1 = self._tracer._now_us()
+            self._tracer._pop(self, self._t0, t1 - self._t0)
+        if self._ann is not None:
+            st = _open_spans()
+            if st and st[-1] is self:
+                st.pop()
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         return False
 
 
@@ -121,10 +193,13 @@ class Tracer:
 
     # ------------------------------------------------------------ spans
     def span(self, name: str, cat: str = "", **args):
-        """Context manager recording one complete ("X") event."""
-        if not self.enabled:
+        """Context manager recording one complete ("X") event, and a
+        profiler annotation while a profiler session runs."""
+        annotate = profiling()
+        if not self.enabled and not annotate:
             return _NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self if self.enabled else None, name, cat, args,
+                     annotate)
 
     def _push(self, sp: _Span) -> None:
         self._stack().append(sp)
@@ -150,7 +225,12 @@ class Tracer:
 
     # --------------------------------------------------------- counters
     def counter(self, name: str, value, cat: str = "counter") -> None:
-        """Record one counter sample (Chrome ``"C"`` event)."""
+        """Record one counter sample (Chrome ``"C"`` event); under a
+        profiler session, also an arg of the innermost open span."""
+        if profiling():
+            st = _open_spans()
+            if st and st[-1]._ann is not None:
+                st[-1]._ann.set_metadata(**{name: _stat(value)})
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "C",
@@ -160,7 +240,11 @@ class Tracer:
             self._events.append(ev)
 
     def instant(self, name: str, cat: str = "mark", **args) -> None:
-        """Record one instant ("i") event (a point-in-time mark)."""
+        """Record one instant ("i") event (a point-in-time mark); under
+        a profiler session, also a zero-length annotation."""
+        if profiling():
+            with _annotation(name, **{k: _stat(v) for k, v in args.items()}):
+                pass
         if not self.enabled:
             return
         ev = {"name": name, "cat": cat, "ph": "i", "s": "t",
@@ -251,11 +335,12 @@ def disable() -> Tracer:
 def span(name: str, cat: str = "", **args):
     """Module-level span against the global tracer.
 
-    The disabled fast path returns a shared no-op context manager
-    without constructing anything -- safe to call in dispatch loops.
+    With neither sink on, the fast path returns a shared no-op context
+    manager without constructing anything -- safe to call in dispatch
+    loops.
     """
     t = _tracer
-    if not t.enabled:
+    if not t.enabled and not profiling():
         return _NULL_SPAN
     return t.span(name, cat, **args)
 
@@ -263,5 +348,5 @@ def span(name: str, cat: str = "", **args):
 def counter(name: str, value, cat: str = "counter") -> None:
     """Module-level counter sample against the global tracer."""
     t = _tracer
-    if t.enabled:
+    if t.enabled or profiling():
         t.counter(name, value, cat)

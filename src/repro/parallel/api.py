@@ -39,7 +39,6 @@ from repro.core.allreduce import (all_gather_flat, allreduce_flat,
 from repro.core.cost_model import Fabric, TPU_V5E_ICI
 from repro.core.monoid import CombineLike, resolve_combine
 from repro.core.schedule import ShapeError, max_r
-from repro.obs import trace as obs_trace
 from repro.topology.fabric import Topology
 
 AxisName = Union[str, Tuple[str, ...]]
@@ -66,10 +65,6 @@ class ParallelConfig:
     tuning: bool = False           # consult the measured tuning table
     # (repro.tuning) for gradient-sync schedule choice; False = analytic
     # cost model only
-    trace: bool = False            # emit gradient-sync spans into the
-    # global tracer (repro.obs.trace) when it is enabled; spans are
-    # trace-time only (staging inside jit), runtime timelines come from
-    # the blocking replay in repro.obs.instrument
     decode_collectives: str = "xla"  # xla | plan  (serving decode-path TP
     # psum / vocab all-gather: "plan" runs them on ExecPlan schedules
     # picked by autotune.choose() at the decode message size -- the
@@ -149,8 +144,9 @@ def dp_grad_allreduce(tree, pc: ParallelConfig, *, mean: bool = True,
 
     ``compute_overlap_us`` is the backward-overlap hint forwarded to the
     autotuner on the flat path (the hierarchical path prices per level
-    and takes no hint today); ``tag`` labels this dispatch's executor
-    trace span (the overlapped sync passes ``"grad_bucket<k>"``).
+    and takes no hint today); ``tag`` names a scope around this
+    dispatch's ExecPlan ops (the overlapped sync passes
+    ``"grad_bucket<k>"``).
     """
     if pc.dp == 1:
         return tree
@@ -164,16 +160,7 @@ def dp_grad_allreduce(tree, pc: ParallelConfig, *, mean: bool = True,
     if mean and monoid.name not in ("sum", "mean"):
         raise ValueError(f"dp_grad_allreduce(op={monoid.name!r}) needs "
                          f"mean=False (mean only composes with sum)")
-    if pc.trace:
-        n_elems = sum(int(x.size) for x in jax.tree.leaves(tree))
-        attrs = {} if tag is None else {"tag": tag}
-        sp = obs_trace.span("dp_grad_allreduce", cat="trace",
-                            dp=pc.dp, n_elems=n_elems, op=monoid.name,
-                            hierarchical=pc.hierarchical_dp,
-                            tuning=pc.tuning, **attrs)
-    else:
-        sp = obs_trace._NULL_SPAN
-    with sp:
+    with jax.named_scope("grad_sync"):
         if pc.hierarchical_dp:
             outer = pc.topology.outer
             if pc.grad_r is not None and \

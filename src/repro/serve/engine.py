@@ -231,14 +231,47 @@ class Engine:
         return (chunk if any_prefill else 1), live
 
     def step(self) -> int:
-        """Admit + run one device tick.  Returns #tokens generated."""
-        self._admit()
-        if all(s is None for s in self.slots):
-            return 0
+        """Admit + run one device tick.  Returns #tokens generated.
+
+        The tick is one ``engine.tick`` span over leaf spans in order:
+        ``engine.admit``, ``engine.build`` (plan, host arrays, uploads),
+        ``engine.dispatch`` (the serve step), ``engine.fetch`` (the
+        logits to the host; only on ticks that emit a token) and
+        ``engine.sample`` (sampling, streams, retiring).  Its args are
+        the tick's counters (see ``repro.obs.trace``: computed only
+        while a sink is on)."""
+        span = obs_trace.span
+        with span("engine.tick", cat="serve") as tick:
+            with span("engine.admit", cat="serve"):
+                self._admit()
+            if all(s is None for s in self.slots):
+                return 0
+            with span("engine.build", cat="serve"):
+                S, rows, toks, n_new, emit, ctx = self._build_tick()
+            if tick is not obs_trace._NULL_SPAN:
+                tokens = int(n_new.sum())
+                tick.set(s=S, rows=len(rows), queued=len(self.queue),
+                         tokens=tokens, pad_slots=self.B * S - tokens,
+                         kv_blocks_used=sum(m.n_used for m in self.kv))
+            with span("engine.dispatch", cat="serve"):
+                logits, self.caches = self.bundle.serve_step(
+                    self.params, toks, self.caches, ctx)
+            lg = None
+            if emit:
+                with span("engine.fetch", cat="serve"):
+                    lg = np.asarray(logits[:, 0], np.float32)
+            with span("engine.sample", cat="serve"):
+                self._retire_tick(S, rows, n_new, emit, lg)
+        return len(emit)
+
+    def _build_tick(self):
+        """Plan one tick and upload its inputs.  Returns (S, rows,
+        tokens, n_new, rows that emit a token this tick, page context)."""
         S, rows = self._plan_tick()
         toks = np.zeros((self.B, S), np.int32)
         n_new = np.zeros(self.B, np.int32)
         reset = np.zeros(self.B, bool)
+        emit = []
         for b in rows:
             s = self.slots[b]
             if s.prefilling:
@@ -250,6 +283,9 @@ class Engine:
             n_new[b] = n
             reset[b] = s.fresh
             s.fresh = False
+            # mid-prefill rows' logits are not meaningful yet
+            if s.fed + n >= len(s.req.prompt) + len(s.req.out_tokens):
+                emit.append(b)
         table = np.concatenate([m.table for m in self.kv], axis=0)
         ctx = PageCtx(block_table=jnp.asarray(table),
                       # a host copy: on the CPU backend the device array
@@ -260,30 +296,23 @@ class Engine:
                       lengths=jnp.asarray(self.lengths.copy()),
                       n_new=jnp.asarray(n_new),
                       reset=jnp.asarray(reset))
-        prefill = bool((n_new > 1).any()) or any(
-            self.slots[b].prefilling for b in rows if self.slots[b])
-        with obs_trace.span("engine.tick", cat="serve", s=S,
-                            live=len(rows), queued=len(self.queue),
-                            prefill=prefill):
-            logits, self.caches = self.bundle.serve_step(
-                self.params, jnp.asarray(toks), self.caches, ctx)
+        return S, rows, jnp.asarray(toks), n_new, emit, ctx
+
+    def _retire_tick(self, S: int, rows, n_new, emit, lg) -> None:
+        """Book the tick's fed tokens, then sample each emitting row's
+        token from the fetched logits ``lg``, stream it, and retire the
+        rows that are done."""
         self._n_ticks += 1
         self._n_prefill_ticks += int(S > 1)
         self.lengths += n_new
-        lg = None   # fetched lazily: pure-prefill ticks never read logits
-        emitted = 0
         for b in rows:
+            self.slots[b].fed += int(n_new[b])
+        for b in emit:
             s = self.slots[b]
-            s.fed += int(n_new[b])
-            if s.fed < len(s.req.prompt) + len(s.req.out_tokens):
-                continue      # mid-prefill: logits not meaningful yet
-            if lg is None:
-                lg = np.asarray(logits[:, 0], np.float32)
             tok = self._sample(lg[b], s.req.uid, len(s.req.out_tokens))
             s.req.out_tokens.append(tok)
             s.next_tok = tok
             self._n_tokens += 1
-            emitted += 1
             now = _now_us()
             if s.req.t_first_token_us is None:
                 s.req.t_first_token_us = now
@@ -300,7 +329,6 @@ class Engine:
                 self.kv[shard].retire(local)
                 self.slots[b] = None
                 self.lengths[b] = 0
-        return emitted
 
     def run(self) -> None:
         """Drive ticks until queue and slots drain."""

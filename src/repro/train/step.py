@@ -284,17 +284,18 @@ def make_train_step(cfg: ModelConfig, pc: ParallelConfig, mesh: Mesh,
                                            fabric=fabric)
         else:
             grads = sync_grads_dp(grads, specs, pc, fabric)
-        if pc.param_mode == "dp":
-            grads = clip_by_global_norm(grads, oc)
-        elif pc.param_mode == "zero1" and pc.dp > 1:
-            grads = clip_by_global_norm(grads, oc,
-                                        sq_psum_axes=pc.dp_axis_name)
-        if pc.param_mode == "zero1":
-            new_params, new_opt = apply_updates_zero1(
-                params, grads, opt_state, oc, pc)
-        else:
-            new_params, new_opt = apply_updates_dp(
-                params, grads, opt_state, oc, pc)
+        with jax.named_scope("optimizer"):
+            if pc.param_mode == "dp":
+                grads = clip_by_global_norm(grads, oc)
+            elif pc.param_mode == "zero1" and pc.dp > 1:
+                grads = clip_by_global_norm(grads, oc,
+                                            sq_psum_axes=pc.dp_axis_name)
+            if pc.param_mode == "zero1":
+                new_params, new_opt = apply_updates_zero1(
+                    params, grads, opt_state, oc, pc)
+            else:
+                new_params, new_opt = apply_updates_dp(
+                    params, grads, opt_state, oc, pc)
         dp_axes = pc.dp_axis_name
         total_g = lax.psum(total, dp_axes) if pc.dp > 1 else total
         count_g = lax.psum(count.astype(jnp.float32), dp_axes) \
