@@ -8,6 +8,11 @@ the kernel body in Python -- bit-accurate for validation against the
 ``attention`` / ``norm`` expose an ``impl`` switch ("pallas" | "xla") so the
 model stack can pick the XLA path where cost_analysis visibility matters
 (the multi-pod dry-run) and the kernel path on real hardware.
+
+The paged serve path does not come through ``attention``: it attends
+with :func:`repro.models.attention.paged_attention`, grouped by KV head
+over the gathered blocks, and :func:`repro.kernels.ref.flash_attention_ref`
+is its oracle in the tests.
 """
 from __future__ import annotations
 

@@ -101,6 +101,13 @@ def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``q_positions``: (Sq,) or per-row (B, Sq) absolute query positions
     overriding the tail-alignment default (cache decode / prefill into
     a larger buffer).
+
+    Training (with its backward), the dense-cache and the seq-sharded
+    decode paths attend with this function.  The paged serve path does
+    not: :func:`repro.models.attention.paged_attention` reads the
+    gathered blocks without the repeat and the f32 copies, and this
+    function over :func:`repro.models.attention.paged_view` is its
+    oracle.
     """
     B, Hq, Sq, D = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]

@@ -145,14 +145,10 @@ def attention_block(p, xg, cfg, pc: ParallelConfig, *,
         q, k, v = qkv_project(p, xg, cfg, pc)
         q, k = rope(q, k, positions, theta=cfg.rope_theta)   # (B, S) pos
         cache = paged_cache_update(cache, k, v, paged)
-        k_view, v_view = paged_view(cache, paged.block_table)
-        o = kops.attention(
-            q, k_view, v_view,
-            causal=cfg.causal,
-            window=window,
-            kv_valid=paged.lengths + paged.n_new,            # per row
-            q_positions=positions,                           # (B, S)
-            impl=attn_impl)
+        o = paged_attention(q, cache.k, cache.v, paged.block_table,
+                            paged.lengths + paged.n_new,     # per row
+                            positions,                       # (B, S)
+                            window, causal=cfg.causal)
         o = o.swapaxes(1, 2).reshape(B, S, -1)
         out = jax.lax.dot_general(
             o, cast_weight(p["wo"], o.dtype), (((2,), (0,)), ((), ())),
@@ -255,11 +251,60 @@ def paged_cache_update(cache: PagedKV, k_new, v_new, ctx: PageCtx):
     return PagedKV(k, v)
 
 
+def paged_attention(q, pool_k, pool_v, block_table, kv_valid, q_positions,
+                    window: Optional[int] = None, *, causal: bool = True):
+    """Attention of every slot's new tokens over its paged cache.
+
+    q: ``(B, Hq, S, hd)``; pools ``(n_blocks, Hkv, bs, hd)``;
+    ``block_table`` ``(B, nb_max)``; ``kv_valid`` ``(B,)`` written
+    length of each slot; ``q_positions`` ``(B, S)`` absolute positions.
+    Returns ``(B, Hq, S, hd)`` in q's dtype.
+
+    The same masks as :func:`repro.kernels.ref.flash_attention_ref`
+    over :func:`paged_view` (its oracle in the tests), and the products
+    the TPU runs for that oracle's f32 einsums at default precision
+    (bf16 operands, f32 accumulation), without its copies: the query
+    heads of one KV head form a group ``G = Hq // Hkv`` that contracts
+    against the gathered ``(B, nb_max, Hkv, bs, hd)`` view with the KV
+    head as a batch dimension, so K/V are never repeated nor copied to
+    f32; the softmax runs in f32.  A row with nothing to attend to
+    (``kv_valid`` 0) gives zeros.  Forward only: the serve path has no
+    backward.
+    """
+    B, Hq, S, hd = q.shape
+    _, Hkv, bs, _ = pool_k.shape
+    nbm = block_table.shape[1]
+    k = pool_k[block_table]                       # (B, nbm, Hkv, bs, hd)
+    v = pool_v[block_table]
+    qg = q.astype(k.dtype).reshape(B, Hkv, Hq // Hkv, S, hd)
+    logits = jnp.einsum("bkgsd,bnktd->bkgsnt", qg, k,
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    kpos = jnp.arange(nbm * bs, dtype=jnp.int32).reshape(nbm, bs)
+    qpos = q_positions.astype(jnp.int32)[:, :, None, None]   # (B, S, 1, 1)
+    mask = kpos < kv_valid.astype(jnp.int32)[:, None, None, None]
+    if causal:
+        mask = mask & (kpos <= qpos)
+    if window is not None:
+        mask = mask & (kpos > qpos - window)
+    mask = mask[:, None, None]                    # (B, 1, 1, S|1, nbm, bs)
+    logits = jnp.where(mask, logits, -jnp.inf)
+    m = jnp.max(logits, axis=(-2, -1), keepdims=True)
+    p = jnp.exp(logits - jnp.where(jnp.isfinite(m), m, 0.0))
+    den = jnp.sum(p, axis=(-2, -1))               # (B, Hkv, G, S)
+    o = jnp.einsum("bkgsnt,bnktd->bkgsd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    o = o / jnp.maximum(den, 1e-30)[..., None]
+    return o.reshape(B, Hq, S, hd).astype(q.dtype)
+
+
 def paged_view(cache: PagedKV, block_table):
     """Gather each slot's logical cache view from the pool.
 
     Returns ``(B, H, nb_max * bs, hd)`` K/V where row ``b``'s sequence
     axis is its own logical positions (garbage past ``kv_valid``).
+    The serve path attends with :func:`paged_attention`, which reads
+    the gathered blocks without this view's copy; the view is what its
+    oracle attends over.
     """
     B, nbm = block_table.shape
     _, H, bs, hd = cache.k.shape

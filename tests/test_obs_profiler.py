@@ -5,7 +5,9 @@ While a JAX profiler session runs, ``repro.obs.trace`` spans land on the
 profile's host plane with their args as stats; the engine's ticks carry
 their counters there; and the device programs' ``op_name`` paths carry
 the scopes the benchmark's per-layer metrics read (``attn``, ``mlp``,
-``weight_cast``, ``head``, ``optimizer``, ``grad_sync``, ``execplan.*``).
+``weight_cast``, ``head``, ``optimizer``, ``grad_sync``, ``execplan.*``);
+and the paged serve step's attention reads the gathered K/V blocks with
+no copy repeated per query head and no f32 copy.
 """
 from __future__ import annotations
 
@@ -205,13 +207,13 @@ _LOWER = textwrap.dedent("""
     mesh1 = make_mesh((1, 1), ("data", "model"), devices=dev[:1])
     pc1 = parallel_config_for(mesh1, param_mode="dp")
     sb = make_paged_serve_step(cfg, pc1, mesh1)
-    B, nb = 2, 4
-    caches = init_paged_caches(cfg, pc1, B, 1 + B * nb, 16)
+    B, S, nb, bs = 2, 8, 4, 8
+    caches = init_paged_caches(cfg, pc1, B, 1 + B * nb, bs)
     ctx = PageCtx(block_table=jnp.zeros((B, nb), jnp.int32),
                   lengths=jnp.zeros(B, jnp.int32),
                   n_new=jnp.ones(B, jnp.int32), reset=jnp.zeros(B, bool))
     serve = sb.serve_step.lower(sb.params_shapes,
-                                jax.ShapeDtypeStruct((B, 8), jnp.int32),
+                                jax.ShapeDtypeStruct((B, S), jnp.int32),
                                 sds(caches), sds(ctx)).as_text(
         dialect="hlo", debug_info=True)
 
@@ -224,14 +226,29 @@ _LOWER = textwrap.dedent("""
                              ).as_text(dialect="hlo", debug_info=True)
     for name, text in (("train", train), ("serve", serve), ("plan", plan)):
         print(name, " ".join(scopes(text)))
+    with open(sys.argv[1], "w") as f:
+        f.write(serve)
+    print("serve_dims", B, S, nb, bs, cfg.n_kv_heads, cfg.n_heads, cfg.hd)
 """)
 
 
-def test_compiled_programs_carry_the_named_scopes():
+def _instructions(hlo, shape, op=r"\w+"):
+    """``op_name`` of every ``op`` instruction of ``hlo`` whose result has
+    the shape ``shape`` (e.g. ``"f32[2,4,32,16]"``); "" where it has
+    none."""
+    pat = re.compile(r"^\s*(?:ROOT )?\S+ = " + re.escape(shape)
+                     + r"(?:\{[^}]*\})? (?:" + op + r")\(")
+    return [(re.findall(r'op_name="([^"]*)"', line) or [""])[0]
+            for line in hlo.splitlines() if pat.match(line)]
+
+
+def test_compiled_programs_carry_the_named_scopes(tmp_path):
     env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2")
-    out = subprocess.run([sys.executable, "-c", _LOWER], capture_output=True,
-                         text=True, env=env, timeout=600)
+    serve_hlo = tmp_path / "serve.hlo"
+    out = subprocess.run([sys.executable, "-c", _LOWER, str(serve_hlo)],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
     got = {line.split(" ", 1)[0]: set(line.split()[1:])
            for line in out.stdout.splitlines() if line}
@@ -243,3 +260,23 @@ def test_compiled_programs_carry_the_named_scopes():
     plan = got["plan"]
     assert any(re.fullmatch(r"execplan\.\w+", t) for t in plan)
     assert {"tick0", "combine"} <= plan
+
+    # the paged serve step attends over the gathered blocks as they are:
+    # no K/V repeated per query head of a GQA group, no f32 K/V view
+    dims = next(line.split()[1:] for line in out.stdout.splitlines()
+                if line.startswith("serve_dims "))
+    B, S, nb, bs, hkv, hq, hd = map(int, dims)
+    G, T = hq // hkv, nb * bs
+    assert G > 1
+    serve = serve_hlo.read_text()
+    view = f"[{B},{nb},{hkv},{bs},{hd}]"      # pool[block_table]
+    gathered = _instructions(serve, "bf16" + view, "gather")
+    assert gathered and all("/attn/" in n for n in gathered), gathered
+    for dtype in ("bf16", "f32"):
+        assert not _instructions(serve, f"{dtype}[{B},{hkv},{G},{T},{hd}]")
+        assert not _instructions(serve, f"{dtype}[{B},{hq},{T},{hd}]")
+    for shape in (view, f"[{B},{hkv},{T},{hd}]"):
+        assert not _instructions(serve, "f32" + shape)
+    scores = _instructions(serve, f"f32[{B},{hkv},{G},{S},{nb},{bs}]",
+                           "dot")
+    assert scores and all("/attn/" in n for n in scores), scores
