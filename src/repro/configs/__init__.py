@@ -15,6 +15,7 @@ ARCHS = [
     "pixtral_12b",
     "mixtral_8x7b",
     "deepseek_moe_16b",
+    "deepseek_v2_lite",
     "recurrentgemma_2b",
     "xlstm_1_3b",
 ]

@@ -1,4 +1,5 @@
-"""GQA/MQA/SWA attention with Megatron-style TP sharding + KV caches.
+"""GQA/MQA/SWA and latent attention with Megatron-style TP sharding +
+KV caches.
 
 Sharding: query heads are sharded over the TP axis.  KV projections are
 sharded over KV heads when n_kv_heads >= tp; otherwise (GQA groups wider
@@ -6,6 +7,14 @@ than one device, or MQA) the KV projection is *replicated* and each device
 dynamically slices the KV head(s) its query heads attend to.  Replicated
 KV grads are exact under a TP psum because each device's grad carries only
 its own query heads' contribution (disjoint slices of the true gradient).
+
+Latent attention (MLA, DeepSeek-V2; ``cfg.mla``) is :func:`mla_block`.
+Its caches hold one latent per token, ``[c, k_pe]`` of ``kv_lora_rank +
+qk_rope_head_dim`` values, the same on every TP rank
+(:class:`PagedLatent`, :class:`LatentCache`); query heads shard over TP.
+Training attends in the published form, keys and values expanded from
+the latent; every cached path attends in the absorbed form over the
+latent itself, through :func:`paged_attention`.
 """
 from __future__ import annotations
 
@@ -16,7 +25,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import ops as kops
-from repro.models.layers import COMPUTE_DTYPE, cast_weight, dense, rope
+from repro.models.layers import (COMPUTE_DTYPE, attention_scale,
+                                 cast_weight, dense, norm_apply, rope,
+                                 rope_pairs)
 from repro.parallel.api import ParallelConfig, tp_rank
 
 
@@ -47,6 +58,24 @@ class PagedKV(NamedTuple):
 
     k: jnp.ndarray
     v: jnp.ndarray
+
+
+class PagedLatent(NamedTuple):
+    """Paged cache of latent attention: one ``(n_blocks, 1, block_size,
+    kv_lora_rank + qk_rope_head_dim)`` pool per layer holding ``[c,
+    k_pe]`` of each token (the normalised latent, then the roped shared
+    key), with :class:`PagedKV`'s block table, garbage block and
+    per-row lengths."""
+
+    kv: jnp.ndarray
+
+
+class LatentCache(NamedTuple):
+    """Dense cache of latent attention: ``(B, 1, S_max, kv_lora_rank +
+    qk_rope_head_dim)`` and, as :class:`KVCache`, the tokens written."""
+
+    kv: jnp.ndarray
+    pos: jnp.ndarray
 
 
 class PageCtx(NamedTuple):
@@ -140,6 +169,11 @@ def attention_block(p, xg, cfg, pc: ParallelConfig, *,
     updated cache (decode path).
     """
     B, S, _ = xg.shape
+    if cfg.mla:
+        assert not (rolling or seq_shard or window), \
+            "latent attention has no window, rolling or seq-sharded cache"
+        return mla_block(p, xg, cfg, pc, positions=positions, cache=cache,
+                         paged=paged, attn_impl=attn_impl)
     if isinstance(cache, PagedKV):
         assert paged is not None, "PagedKV caches need a PageCtx"
         q, k, v = qkv_project(p, xg, cfg, pc)
@@ -222,9 +256,26 @@ def _pool_heads(cfg, pc: ParallelConfig) -> int:
 
 def init_paged_pool(cfg, pc: ParallelConfig, n_blocks: int,
                     block_size: int, dtype=COMPUTE_DTYPE) -> PagedKV:
-    """One layer's physical KV block pool (block 0 = garbage block)."""
+    """One layer's physical KV block pool (block 0 = garbage block): K
+    and V pools of the layer's KV heads, or for latent attention one
+    latent pool (:class:`PagedLatent`)."""
+    if cfg.mla:
+        return PagedLatent(jnp.zeros((n_blocks, 1, block_size,
+                                      _latent_width(cfg)), dtype))
     shape = (n_blocks, _pool_heads(cfg, pc), block_size, cfg.hd)
     return PagedKV(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+def _page_slots(ctx: PageCtx, S: int, pool_shape):
+    """Physical ``(block, offset)`` of token ``t`` of each row, ``(B,
+    S)`` each; padding tokens get the out-of-range block ``n_blocks``."""
+    nb, _, bs, _ = pool_shape
+    pos = ctx.lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    valid = jnp.arange(S)[None, :] < ctx.n_new[:, None]          # (B, S)
+    logical = jnp.clip(pos // bs, 0, ctx.block_table.shape[1] - 1)
+    blk = jnp.take_along_axis(ctx.block_table, logical, axis=1)  # (B, S)
+    blk = jnp.where(valid, blk, nb)          # OOB sentinel: dropped
+    return blk, pos % bs
 
 
 def paged_cache_update(cache: PagedKV, k_new, v_new, ctx: PageCtx):
@@ -236,14 +287,7 @@ def paged_cache_update(cache: PagedKV, k_new, v_new, ctx: PageCtx):
     block index and dropped by the scatter -- they neither advance any
     slot nor scribble on another slot's blocks.
     """
-    B, H, S, hd = k_new.shape
-    nb, _, bs, _ = cache.k.shape
-    pos = ctx.lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(S)[None, :] < ctx.n_new[:, None]          # (B, S)
-    logical = jnp.clip(pos // bs, 0, ctx.block_table.shape[1] - 1)
-    blk = jnp.take_along_axis(ctx.block_table, logical, axis=1)  # (B, S)
-    blk = jnp.where(valid, blk, nb)          # OOB sentinel: dropped
-    off = pos % bs
+    blk, off = _page_slots(ctx, k_new.shape[2], cache.k.shape)
     kk = jnp.swapaxes(k_new, 1, 2).astype(cache.k.dtype)   # (B, S, H, hd)
     vv = jnp.swapaxes(v_new, 1, 2).astype(cache.v.dtype)
     k = cache.k.at[blk, :, off].set(kk, mode="drop")
@@ -252,13 +296,18 @@ def paged_cache_update(cache: PagedKV, k_new, v_new, ctx: PageCtx):
 
 
 def paged_attention(q, pool_k, pool_v, block_table, kv_valid, q_positions,
-                    window: Optional[int] = None, *, causal: bool = True):
+                    window: Optional[int] = None, *, causal: bool = True,
+                    scale: Optional[float] = None,
+                    v_dim: Optional[int] = None):
     """Attention of every slot's new tokens over its paged cache.
 
-    q: ``(B, Hq, S, hd)``; pools ``(n_blocks, Hkv, bs, hd)``;
-    ``block_table`` ``(B, nb_max)``; ``kv_valid`` ``(B,)`` written
-    length of each slot; ``q_positions`` ``(B, S)`` absolute positions.
-    Returns ``(B, Hq, S, hd)`` in q's dtype.
+    q: ``(B, Hq, S, hd)``; pools ``(n_blocks, Hkv, bs, hd)`` and
+    ``(n_blocks, Hkv, bs, dv)``; ``block_table`` ``(B, nb_max)``;
+    ``kv_valid`` ``(B,)`` written length of each slot; ``q_positions``
+    ``(B, S)`` absolute positions.  Returns ``(B, Hq, S, dv)`` in q's
+    dtype.  ``scale`` defaults to ``hd ** -0.5``; ``v_dim`` takes the
+    values as the first ``v_dim`` columns of ``pool_v`` (latent
+    attention passes its one latent pool as both, ``Hkv = 1``).
 
     The same masks as :func:`repro.kernels.ref.flash_attention_ref`
     over :func:`paged_view` (its oracle in the tests), and the products
@@ -276,9 +325,12 @@ def paged_attention(q, pool_k, pool_v, block_table, kv_valid, q_positions,
     nbm = block_table.shape[1]
     k = pool_k[block_table]                       # (B, nbm, Hkv, bs, hd)
     v = pool_v[block_table]
+    if v_dim is not None:
+        v = v[..., :v_dim]
     qg = q.astype(k.dtype).reshape(B, Hkv, Hq // Hkv, S, hd)
     logits = jnp.einsum("bkgsd,bnktd->bkgsnt", qg, k,
-                        preferred_element_type=jnp.float32) * hd ** -0.5
+                        preferred_element_type=jnp.float32) * (
+        hd ** -0.5 if scale is None else scale)
     kpos = jnp.arange(nbm * bs, dtype=jnp.int32).reshape(nbm, bs)
     qpos = q_positions.astype(jnp.int32)[:, :, None, None]   # (B, S, 1, 1)
     mask = kpos < kv_valid.astype(jnp.int32)[:, None, None, None]
@@ -294,7 +346,7 @@ def paged_attention(q, pool_k, pool_v, block_table, kv_valid, q_positions,
     o = jnp.einsum("bkgsnt,bnktd->bkgsd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     o = o / jnp.maximum(den, 1e-30)[..., None]
-    return o.reshape(B, Hq, S, hd).astype(q.dtype)
+    return o.reshape(B, Hq, S, v.shape[-1]).astype(q.dtype)
 
 
 def paged_view(cache: PagedKV, block_table):
@@ -319,6 +371,11 @@ def paged_view(cache: PagedKV, block_table):
 def init_cache(cfg, pc: ParallelConfig, batch_local: int, max_len: int,
                *, rolling_window: Optional[int] = None,
                seq_shard: bool = False, dtype=COMPUTE_DTYPE) -> KVCache:
+    if cfg.mla:
+        assert rolling_window is None and not seq_shard
+        return LatentCache(jnp.zeros((batch_local, 1, max_len,
+                                      _latent_width(cfg)), dtype),
+                           jnp.int32(0))
     if attn_replicated(cfg, pc):
         H = cfg.n_kv_heads
     elif kv_replicated(cfg, pc) and pc.tp > 1:
@@ -393,3 +450,84 @@ def seq_shard_decode(p, xg, cfg, pc: ParallelConfig, *,
     o = (num / jnp.maximum(den, 1e-30)[..., None]).astype(xg.dtype)
     o = o.swapaxes(1, 2).reshape(B, S, -1)                # (B, 1, Hq*hd)
     return o, new_cache
+
+
+# ---------------------------------------------------------------- latent
+def _latent_width(cfg) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def mla_block(p, xg, cfg, pc: ParallelConfig, *, positions, cache=None,
+              paged: Optional[PageCtx] = None, attn_impl: str = "xla"):
+    """Multi-head latent attention (DeepSeek-V2, no query compression).
+
+    Per token: ``q = x W_q`` -> heads of ``[q_nope, q_pe]``; ``[c, k_pe]
+    = x W_kva``, ``c`` RMS-normalised (``kv_norm``); ``[k_nope, v] = c
+    W_kvb`` per head; ``q_pe`` and the one shared ``k_pe`` are roped
+    (:func:`~repro.models.layers.rope_pairs`, with YaRN); the logits of
+    ``[q_nope, q_pe] . [k_nope, k_pe]`` scale by
+    :func:`~repro.models.layers.attention_scale`; ``o = softmax . v``,
+    then ``o W_o``.
+
+    Without a cache (training) that is computed as written.  With one,
+    the cache holds ``[c, k_pe]`` per token and attention runs absorbed:
+    queries ``[q_nope W_UK^T, q_pe]`` attend over the latent itself, the
+    values are ``c``, and ``W_UV`` (``W_kvb``'s value columns) is applied
+    to the result.  Returns the (B, S, d) output, partial over TP, and
+    the updated cache."""
+    B, S, _ = xg.shape
+    hl = local_q_heads(cfg, pc)
+    nope, rd, r, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                       cfg.kv_lora_rank, cfg.v_head_dim)
+    scale = attention_scale(cfg)
+    q = dense(xg, p["wq"]).reshape(B, S, hl, nope + rd)
+    kva = dense(xg, p["wkva"])                               # (B, S, r+rd)
+    c = norm_apply(p["kv_norm"], kva[..., :r], eps=cfg.norm_eps)
+    q_pe = rope_pairs(q[..., nope:], positions, theta=cfg.rope_theta,
+                      yarn=cfg.yarn)
+    k_pe = rope_pairs(kva[..., r:], positions, theta=cfg.rope_theta,
+                      yarn=cfg.yarn)
+    wkvb = cast_weight(p["wkvb"], xg.dtype).reshape(r, hl, nope + vd)
+    if cache is None:
+        kv = jnp.einsum("bsr,rhe->bshe", c, wkvb,
+                        preferred_element_type=xg.dtype)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe[:, :, None], (B, S, hl, rd))], -1)
+        qf = jnp.concatenate([q[..., :nope], q_pe], -1)
+        o = kops.attention(qf.swapaxes(1, 2), k.swapaxes(1, 2),
+                           kv[..., nope:].swapaxes(1, 2), causal=cfg.causal,
+                           scale=scale, impl=attn_impl)
+        o = o.swapaxes(1, 2)                                 # (B, S, hl, vd)
+    else:
+        q_lat = jnp.einsum("bshn,rhn->bhsr", q[..., :nope], wkvb[..., :nope],
+                           preferred_element_type=jnp.float32)
+        q_abs = jnp.concatenate([q_lat.astype(xg.dtype),
+                                 q_pe.swapaxes(1, 2)], -1)  # (B, hl, S, r+rd)
+        new = jnp.concatenate([c, k_pe], -1)                 # (B, S, r+rd)
+        if isinstance(cache, PagedLatent):
+            assert paged is not None, "PagedLatent caches need a PageCtx"
+            blk, off = _page_slots(paged, S, cache.kv.shape)
+            pool = cache.kv.at[blk, 0, off].set(
+                new.astype(cache.kv.dtype), mode="drop")
+            cache = PagedLatent(pool)
+            table, valid = paged.block_table, paged.lengths + paged.n_new
+            qpos = positions
+        else:
+            pool = lax.dynamic_update_slice(
+                cache.kv, new[:, None].astype(cache.kv.dtype),
+                (0, 0, cache.pos, 0))
+            cache = LatentCache(pool, cache.pos + S)
+            table = jnp.arange(B, dtype=jnp.int32)[:, None]
+            valid = jnp.full((B,), cache.pos, jnp.int32)
+            qpos = jnp.broadcast_to(positions, (B, S))
+        with jax.named_scope("latent_attn"):
+            o_lat = paged_attention(q_abs, pool, pool, table, valid, qpos,
+                                    causal=cfg.causal, scale=scale, v_dim=r)
+        o = jnp.einsum("bhsr,rhv->bshv", o_lat, wkvb[..., nope:],
+                       preferred_element_type=xg.dtype)
+    o = o.reshape(B, S, hl * vd)
+    out = jax.lax.dot_general(
+        o, cast_weight(p["wo"], o.dtype), (((2,), (0,)), ((), ())),
+        preferred_element_type=o.dtype)
+    return out, cache

@@ -13,16 +13,59 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """A routed expert layer.
+
+    ``n_experts`` is the router's width: every token is routed over all
+    of them, ``top_k`` at a time.  ``norm_topk`` rescales the ``top_k``
+    gate probabilities to sum to one (Mixtral); with it off they stay
+    the softmax's own (DeepSeek's ``norm_topk_prob: false``).
+
+    ``held = (i, n)`` says which experts this layer holds: chip ``i`` of
+    ``n`` sharing the layer under expert parallelism holds the contiguous
+    range ``i * E / n ... (i + 1) * E / n - 1``.  Its parameters are
+    those experts alone; the layer computes their part of the result for
+    the tokens routed to them, and the part of the absent experts is left
+    out (the chips that hold them add it).  ``(0, 1)`` holds them all.
+    """
     n_experts: int
     top_k: int
     d_expert: int                 # ffn width per routed expert
     n_shared: int = 0             # always-on shared experts (DeepSeek-MoE)
     d_shared: int = 0             # ffn width of the fused shared expert
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the all-to-all dispatch's queues only
     first_dense: int = 0          # leading dense layers (DeepSeek-MoE: 1)
     d_first_dense: int = 0        # ffn width of those dense layers
     aux_loss_weight: float = 0.01
     z_loss_weight: float = 1e-3
+    norm_topk: bool = True        # renormalise the top-k gate weights
+    held: Tuple[int, int] = (0, 1)   # (chip i, of n chips) -- see above
+
+    @property
+    def n_held(self) -> int:
+        i, n = self.held
+        assert 0 <= i < n and self.n_experts % n == 0, (self.held,
+                                                        self.n_experts)
+        return self.n_experts // n
+
+    @property
+    def first_held(self) -> int:
+        return self.held[0] * self.n_held
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it:
+    the rope frequencies ramp from interpolated (``/ factor``) to the
+    original between the ``beta_fast`` and ``beta_slow`` correction
+    dimensions of ``original_max_position_embeddings``; attention logits
+    scale by ``m(mscale_all_dim) ** 2`` and cos/sin by ``m(mscale) /
+    m(mscale_all_dim)``, where ``m(s) = 0.1 * s * ln(factor) + 1``."""
+    factor: float
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max_position_embeddings: int = 4096
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -45,6 +88,16 @@ class ModelConfig:
     norm_eps: float = 1e-5
     causal: bool = True
     rope_theta: float = 10_000.0
+    # multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), on when
+    # kv_lora_rank > 0: keys and values come from one normalised latent
+    # of kv_lora_rank per token, plus one rope key of qk_rope_head_dim
+    # shared by every head; a head's query is qk_nope_head_dim +
+    # qk_rope_head_dim wide, its value v_head_dim.  No query compression.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Optional[YarnRope] = None       # rope scaling (MLA's rope key)
     tie_embeddings: bool = False
     parallel_residual: bool = False       # Cohere-style parallel attn+mlp
     frontend: Optional[str] = None        # None | "audio" | "vision"
@@ -62,7 +115,14 @@ class ModelConfig:
         return self.head_dim or (self.d_model // self.n_heads)
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def q_dim(self) -> int:
+        """Width of the attention output that the out-projection reads."""
+        if self.mla:
+            return self.n_heads * self.v_head_dim
         return self.n_heads * self.hd
 
     @property
@@ -133,9 +193,16 @@ class ModelConfig:
         n += self.vocab * d                      # embed
         if not self.tie_embeddings:
             n += self.vocab * d                  # lm head
-        for kind in self.blocks:
+        for i, kind in enumerate(self.blocks):
             n += 2 * d                           # 2 norms
-            if kind in ("attn", "local_attn"):
+            if kind in ("attn", "local_attn") and self.mla:
+                r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+                n += d * self.n_heads * (self.qk_nope_head_dim + rope)
+                n += d * (r + rope) + r
+                n += r * self.n_heads * (self.qk_nope_head_dim
+                                         + self.v_head_dim)
+                n += self.q_dim * d
+            elif kind in ("attn", "local_attn"):
                 n += d * self.q_dim + self.q_dim * d + 2 * d * self.kv_dim
             elif kind == "rglru":
                 w = self.rnn_width or d
@@ -148,15 +215,18 @@ class ModelConfig:
                 w = int(d * self.slstm_proj_factor)
                 n += 4 * d * d + 2 * d * w
             if kind in ("attn", "local_attn"):
-                n += self._mlp_params()
+                n += self._mlp_params(i < len(self.prefix_kinds))
         return n
 
-    def _mlp_params(self) -> int:
+    def _mlp_params(self, dense: bool = False) -> int:
         d = self.d_model
+        if self.moe is not None and dense and self.moe.d_first_dense:
+            per = 3 if self.act in ("swiglu", "geglu") else 2
+            return per * d * self.moe.d_first_dense
         if self.moe is not None:
             m = self.moe
             per = (3 if self.act in ("swiglu", "geglu") else 2)
-            n = m.n_experts * per * d * m.d_expert + d * m.n_experts
+            n = m.n_held * per * d * m.d_expert + d * m.n_experts
             if m.n_shared:
                 n += per * d * m.d_shared
             return n

@@ -6,10 +6,12 @@ are the device-local shards; the companion ``ParamSpec`` tree (built in
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.kernels import ops as kops
@@ -242,3 +244,66 @@ def rope(q, k, positions, *, theta: float):
                                 x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
     return rot(q), rot(k)
+
+
+def _yarn_m(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(dim: int, theta: float, yarn=None):
+    """Inverse frequencies of a ``dim``-wide rope and the factor on its
+    cos/sin.  With :class:`~repro.models.config.YarnRope`, DeepSeek-V2's
+    YaRN: frequencies interpolated by ``factor`` below the ``beta_fast``
+    correction dimension, kept above ``beta_slow``'s, and a linear ramp
+    between them."""
+    base = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn is None:
+        return base.astype(np.float32), 1.0
+
+    def corr(rot):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp               # 1: the original frequency
+    inv = base / yarn.factor * (1.0 - keep) + base * keep
+    scale = (_yarn_m(yarn.factor, yarn.mscale)
+             / _yarn_m(yarn.factor, yarn.mscale_all_dim))
+    return inv.astype(np.float32), scale
+
+
+def attention_scale(cfg) -> float:
+    """Softmax scale of attention logits: ``head_dim ** -0.5``, times
+    YaRN's ``m(mscale_all_dim) ** 2`` where the config scales its rope."""
+    s = cfg.hd ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        s *= _yarn_m(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
+def rope_pairs(x, positions, *, theta: float, yarn=None):
+    """Rotary embedding of ``x`` (B, S, ..., D) at ``positions`` (S,) or
+    (B, S), in DeepSeek-V2's pairing: the columns are read as interleaved
+    pairs ``(2i, 2i + 1)`` and come out de-interleaved, first members
+    then second, each pair rotated by frequency ``i``
+    (:func:`rope_frequencies`).  Angles in fp32, the rotation in x's
+    dtype, as :func:`rope`."""
+    D = x.shape[-1]
+    half = D // 2
+    inv, scale = rope_frequencies(D, theta, yarn)
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    if positions.ndim == 1:
+        ang = ang[None]
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (half,))
+    cos = (jnp.cos(ang) * scale).astype(x.dtype)
+    sin = (jnp.sin(ang) * scale).astype(x.dtype)
+    x = x.reshape(x.shape[:-1] + (half, 2)).swapaxes(-1, -2).reshape(x.shape)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)

@@ -109,8 +109,29 @@ def _mlp_init(ii: _Init, d_ff: int):
     return p, s
 
 
+def _mla_init(ii: _Init):
+    """Latent attention (see :func:`repro.models.attention.mla_block`):
+    query heads shard over TP, the latent projection and its norm are
+    replicated."""
+    cfg = ii.cfg
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    p, s = {}, {}
+    p["wq"], s["wq"] = ii.w((d, H * cfg.hd), tp_dim=1)
+    p["wkva"], s["wkva"] = ii.w((d, r + cfg.qk_rope_head_dim))
+    p["kv_norm"], s["kv_norm"] = {}, {}
+    p["kv_norm"]["w"], s["kv_norm"]["w"] = ii.ones((r,))
+    p["wkvb"], s["wkvb"] = ii.w(
+        (r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), tp_dim=1)
+    p["wo"], s["wo"] = ii.w((cfg.q_dim, d), tp_dim=0,
+                            scale=(cfg.q_dim ** -0.5) / math.sqrt(
+                                2 * cfg.n_layers))
+    return p, s
+
+
 def _attn_init(ii: _Init):
     cfg, pc = ii.cfg, ii.pc
+    if cfg.mla:
+        return _mla_init(ii)
     d = cfg.d_model
     p, s = {}, {}
     repl = attn_replicated(cfg, pc)
@@ -130,7 +151,7 @@ def _moe_init(ii: _Init):
     d = cfg.d_model
     p, s = {"router": {}, "experts": {}}, {"router": {}, "experts": {}}
     p["router"]["w"], s["router"]["w"] = ii.w((d, m.n_experts), tp_dim=None)
-    E = m.n_experts
+    E = m.n_held                  # the experts this layer holds
     p["experts"]["w1"], s["experts"]["w1"] = ii.w((E, d, m.d_expert), tp_dim=2)
     p["experts"]["w3"], s["experts"]["w3"] = ii.w((E, d, m.d_expert), tp_dim=2)
     p["experts"]["w2"], s["experts"]["w2"] = ii.w((E, m.d_expert, d), tp_dim=1)
@@ -377,7 +398,10 @@ def block_apply(kind: str, p, x, cfg: ModelConfig, pc: ParallelConfig, *,
     def mlp(h):
         with jax.named_scope("mlp"):
             if moe_layer and cfg.moe is not None:
-                return moe_lib.moe_apply(p["mlp"], h, cfg, pc)
+                # rows that carry no token this tick are routed nowhere
+                valid = None if paged is None else (
+                    jnp.arange(h.shape[1])[None, :] < paged.n_new[:, None])
+                return moe_lib.moe_apply(p["mlp"], h, cfg, pc, valid)
             return mlp_apply(p["mlp"], h, cfg, pc), jnp.float32(0.0)
 
     if cfg.parallel_residual and _block_has_mlp(cfg, kind):

@@ -1,4 +1,15 @@
-"""Mixture-of-Experts FFN with capacity-based routing.
+"""Mixture-of-Experts FFN: a dropless layer of held experts, and a
+capacity-based all-to-all dispatch.
+
+Where the layer runs without an exchange (:func:`ep_group_size` 1: in
+serving and in single-device training, and under the "tp" dispatch),
+it is dropless (:func:`held_experts_apply`): every token is routed over
+all ``n_experts``, and the layer computes the part of the result that
+the experts it holds (``MoEConfig.held``) give, for every token routed
+to them, with grouped products over the rows sorted by expert
+(``jax.lax.ragged_dot``).  No token is dropped, so a request's output
+does not depend on what else shares its batch.  Rows marked invalid
+(chunk padding, idle slots) are routed nowhere.
 
 Parallelization: experts' ffn widths are sharded over the TP axis exactly
 like a dense MLP (mixtral: 14336/16 = 896 per device; deepseek-moe:
@@ -21,10 +32,11 @@ bit-identical because an all-to-all is a pure permutation.  The default
 assigned pool (8/64 with tp=16) needs no extra collectives at all,
 which the dry-run roofline confirms (see DESIGN.md §MoE).
 
-Routing follows the standard top-k + capacity recipe: per expert a queue
-of C = ceil(T * k / E * capacity_factor) slots; overflowing tokens drop
-(their residual passes through).  Aux losses: load-balance + router
-z-loss.
+The all-to-all dispatch keeps the capacity form, because its exchange
+needs blocks of one size: per expert a queue of C = ceil(T * k / E *
+capacity_factor) slots; overflowing tokens drop (their residual passes
+through).  It holds every expert.  Aux losses (both paths):
+load-balance + router z-loss.
 """
 from __future__ import annotations
 
@@ -36,7 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.allreduce import all_to_all_flat
-from repro.models.layers import cast_weight, dense
+from repro.models.layers import cast_weight, dense, mlp_apply
 from repro.parallel.api import ParallelConfig
 
 
@@ -47,7 +59,9 @@ def capacity(tokens: int, cfg_moe) -> int:
 
 
 def route(p_router, x, cfg_moe):
-    """x (T, d) -> top-k experts, probs and aux losses.
+    """x (T, d) -> top-k experts, probs and aux losses.  The gate is a
+    softmax in f32 over all experts, its top-k taken greedily, and
+    renormalised only where ``cfg_moe.norm_topk``.
 
     Returns (expert_idx (T,k), probs (T,k), aux_loss scalar).
     """
@@ -56,7 +70,8 @@ def route(p_router, x, cfg_moe):
         preferred_element_type=jnp.float32)          # (T, E) f32
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = lax.top_k(probs, cfg_moe.top_k)   # (T, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if cfg_moe.norm_topk:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     # load-balance loss (Switch/GShard): E * sum_e f_e * m_e
     E = cfg_moe.n_experts
@@ -164,17 +179,65 @@ def _experts_apply_ep(pe, xq, cfg, pc: ParallelConfig, ep: int):
 _MOE_TOKEN_CHUNK = 8192
 
 
-def moe_apply(p, xg, cfg, pc: ParallelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """xg (B, S, d) full-seq -> ((B, S, d) partial-over-TP, aux_loss).
+def held_experts_apply(p, x, valid, cfg):
+    """The held experts' part of the layer for x (T, d): each token's
+    gate-weighted sum over those of its top-k experts that this layer
+    holds, dropless.  ``valid`` (T,) bool or None; an invalid row is
+    routed nowhere.  Returns ((T, d) partial over TP, aux_loss)."""
+    m = cfg.moe
+    T, d = x.shape
+    k, El, lo = m.top_k, m.n_held, m.first_held
+    with jax.named_scope("router"):
+        top_e, top_p, aux = route(p["router"], x, m)
+        local = top_e - lo
+        held = (local >= 0) & (local < El)
+        if valid is not None:
+            held = held & valid[:, None]
+        # assignments sorted by held expert; the rest (key El) go last
+        key = jnp.where(held, local, El).reshape(-1)             # (T*k,)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(key, El, dtype=jnp.int32), axis=0)
+        rows = order // k                                        # token
+        gate = jnp.where(held, top_p, 0.0).reshape(-1)[order]
+        live = key[order] < El
+    with jax.named_scope("experts"):
+        pe = p["experts"]
+        xs = jnp.take(x, rows, axis=0)                           # (T*k, d)
 
-    Tokens are processed in chunks of ~8k (scanned, rematted): the
-    (E, C, d) dispatch buffers for a 64-expert layer at 64k tokens would
-    otherwise hold multiple GB live across the backward pass.  Capacity is
+        def grouped(a, w):
+            return lax.ragged_dot(a, cast_weight(w, a.dtype), sizes,
+                                  preferred_element_type=a.dtype)
+
+        g = grouped(xs, pe["w1"])
+        u = grouped(xs, pe["w3"])
+        h = (jax.nn.silu(g) if cfg.act == "swiglu" else jax.nn.gelu(g)) * u
+        y = grouped(h, pe["w2"]).astype(jnp.float32)
+        # rows past the held groups are left unwritten by the product
+        y = jnp.where(live[:, None], y * gate[:, None], 0.0)
+        out = jnp.zeros((T, d), jnp.float32).at[rows].add(y)
+    return out.astype(x.dtype), aux
+
+
+def moe_apply(p, xg, cfg, pc: ParallelConfig, valid=None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """xg (B, S, d) full-seq -> ((B, S, d) partial-over-TP, aux_loss).
+    ``valid`` (B, S) bool marks the rows that hold a token (None: all).
+
+    Without an exchange the layer is :func:`held_experts_apply`, plus
+    the shared expert once.  With the all-to-all dispatch, tokens are
+    processed in chunks of ~8k (scanned, rematted): the (E, C, d)
+    dispatch buffers for a 64-expert layer at 64k tokens would otherwise
+    hold multiple GB live across the backward pass.  Capacity is
     per-chunk, which also bounds worst-case token dropping locality.
     """
     B, S, d = xg.shape
     T = B * S
     x = xg.reshape(T, d)
+    if ep_group_size(pc, cfg.moe.n_experts) == 1:
+        out, aux = held_experts_apply(
+            p, x, None if valid is None else valid.reshape(T), cfg)
+        return _add_shared(p, x, out, cfg, pc).reshape(B, S, d), aux
+    assert cfg.moe.held == (0, 1), "the dispatch holds every expert"
     if T > _MOE_TOKEN_CHUNK:
         nc = -(-T // _MOE_TOKEN_CHUNK)
         pad = nc * _MOE_TOKEN_CHUNK - T
@@ -195,7 +258,8 @@ def moe_apply(p, xg, cfg, pc: ParallelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]
 
 
 def _moe_tokens(p, x, cfg, pc: ParallelConfig):
-    """Route + dispatch + experts + combine for a flat (T, d) token set."""
+    """Route + all-to-all dispatch + experts + combine for a flat (T, d)
+    token set."""
     m = cfg.moe
     T, d = x.shape
     top_e, top_p, aux = route(p["router"], x, m)
@@ -203,11 +267,8 @@ def _moe_tokens(p, x, cfg, pc: ParallelConfig):
 
     xpad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])        # sentinel row
     xq = jnp.take(xpad, eq, axis=0)                                # (E, C, d)
-    ep = ep_group_size(pc, m.n_experts)
-    if ep > 1:
-        yq = _experts_apply_ep(p["experts"], xq, cfg, pc, ep)      # (E, C, d)
-    else:
-        yq = experts_apply(p["experts"], xq, cfg, cfg.act)         # (E, C, d)
+    yq = _experts_apply_ep(p["experts"], xq, cfg, pc,
+                           ep_group_size(pc, m.n_experts))         # (E, C, d)
 
     # combine: token t gets sum_j prob_j * yq[e_j, pos_j]
     C = yq.shape[1]
@@ -219,7 +280,12 @@ def _moe_tokens(p, x, cfg, pc: ParallelConfig):
     gathered = gathered.reshape(T, m.top_k, d)
     out = jnp.sum(gathered * top_p[..., None].astype(gathered.dtype), axis=1)
 
-    if m.n_shared:
-        from repro.models.layers import mlp_apply
-        out = out + mlp_apply(p["shared"], x, cfg, pc).reshape(T, d)
-    return out, aux
+    return _add_shared(p, x, out, cfg, pc), aux
+
+
+def _add_shared(p, x, out, cfg, pc: ParallelConfig):
+    """``out`` plus the shared expert of x (T, d), where there is one."""
+    if not cfg.moe.n_shared:
+        return out
+    with jax.named_scope("shared_expert"):
+        return out + mlp_apply(p["shared"], x, cfg, pc).reshape(out.shape)

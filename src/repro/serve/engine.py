@@ -252,7 +252,10 @@ class Engine:
                 tokens = int(n_new.sum())
                 tick.set(s=S, rows=len(rows), queued=len(self.queue),
                          tokens=tokens, pad_slots=self.B * S - tokens,
-                         kv_blocks_used=sum(m.n_used for m in self.kv))
+                         kv_blocks_used=sum(m.n_used for m in self.kv),
+                         kv_tokens=int((self.lengths + n_new).sum()),
+                         attn_pairs=int((n_new * self.lengths.astype(np.int64)
+                                         + n_new * (n_new + 1) // 2).sum()))
             with span("engine.dispatch", cat="serve"):
                 logits, self.caches = self.bundle.serve_step(
                     self.params, toks, self.caches, ctx)

@@ -144,6 +144,11 @@ def test_engine_ticks_and_their_counters(tmp_path, chrome_off):
         planned.append({"s": S, "rows": len(rows), "tokens": int(n_new.sum()),
                         "pad_slots": eng.B * S - int(n_new.sum()),
                         "kv_blocks_used": sum(m.n_used for m in eng.kv),
+                        "kv_tokens": int((eng.lengths + n_new).sum()),
+                        # each new token scores its causal prefix
+                        "attn_pairs": sum(int(eng.lengths[b]) + i + 1
+                                          for b in range(eng.B)
+                                          for i in range(int(n_new[b]))),
                         "queued": len(eng.queue), "emit": bool(emit)})
         return S, rows, toks, n_new, emit, ctx
 
