@@ -567,24 +567,41 @@ def loss_and_metrics(params, specs, batch, cfg: ModelConfig,
 
 
 # ---------------------------------------------------------------- serving
+def serve_weights(params):
+    """The parameter tree a serve step computes with: every leaf of each
+    block's ``attn`` and ``mlp`` subtrees, the embedding table and the
+    head in the compute dtype, every other leaf (norms, front ends) as
+    given.  Each use casts those leaves so anyway
+    (:func:`~repro.models.layers.cast_weight`), so the values are the
+    same.  On a tree already in that dtype every cast is a no-op that
+    emits nothing, so a caller that serves many steps makes this tree
+    once (:class:`~repro.serve.engine.Engine` does)."""
+    def cast(tree):
+        return jax.tree.map(lambda w: cast_weight(w, COMPUTE_DTYPE), tree)
+
+    def block(bp):
+        return {k: cast(v) if k in ("attn", "mlp") else v
+                for k, v in bp.items()}
+
+    out = dict(params, prefix=[block(bp) for bp in params["prefix"]],
+               cycles={g: block(bp) for g, bp in params["cycles"].items()})
+    for k in ("embed", "head"):
+        if k in params:
+            out[k] = cast(params[k])
+    return out
+
+
 def _serve_params(params, specs, pc: ParallelConfig):
-    """A serve step's parameters: the non-scanned ones FSDP-gathered once,
-    and every block's attention and MLP matrices in the compute dtype,
-    cast once per step under the ``weight_cast`` scope.  Each use casts
-    them so anyway (:func:`~repro.models.layers.cast_weight`), so the
-    values are the same; cast here, before the layer loop, the cast keeps
-    its scope in the compiled program, where XLA would hoist the
+    """A serve step's parameters: :func:`serve_weights` of ``params``
+    under the ``weight_cast`` scope, then the non-scanned ones
+    FSDP-gathered once.  Cast here, before the layer loop, the cast
+    keeps its scope in the compiled program, where XLA would hoist the
     per-layer casts out of the loop itself and drop their metadata."""
+    params = serve_weights(params)
     top = {k: v for k, v in params.items() if k != "cycles"}
     top_specs = {k: v for k, v in specs.items() if k != "cycles"}
-    top = fsdp_gather_tree(top, top_specs, pc)
-
-    def cast(block):
-        return {k: jax.tree.map(lambda w: cast_weight(w, COMPUTE_DTYPE), v)
-                if k in ("attn", "mlp") else v for k, v in block.items()}
-
-    return dict(top, prefix=[cast(bp) for bp in top["prefix"]],
-                cycles={g: cast(bp) for g, bp in params["cycles"].items()})
+    return dict(fsdp_gather_tree(top, top_specs, pc),
+                cycles=params["cycles"])
 
 
 def init_caches(cfg: ModelConfig, pc: ParallelConfig, batch_local: int,

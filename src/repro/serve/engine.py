@@ -46,12 +46,13 @@ from collections import deque
 from dataclasses import dataclass, field, replace
 from typing import Callable, Deque, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models.attention import PageCtx
 from repro.models.config import ModelConfig
-from repro.models.model import init_paged_caches
+from repro.models.model import init_paged_caches, serve_weights
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import Histogram
 from repro.parallel.api import (ParallelConfig, decode_choice_log,
@@ -65,6 +66,27 @@ _RECURRENT = ("rglru", "mlstm", "slstm")
 
 def _now_us() -> float:
     return time.perf_counter_ns() / 1e3
+
+
+def _nbytes(tree) -> int:
+    return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
+
+
+def _prepare_weights(params):
+    """:func:`~repro.models.model.serve_weights` of ``params``, made on
+    the device in one program.  A committed leaf keeps its placement
+    (dp / tp / FSDP); an uncommitted one stays free to move to the
+    serve step's mesh.  ``params`` is not donated: the caller may build
+    more engines from it, and frees its memory by dropping it."""
+    keep = jax.tree.map(
+        lambda a: a.sharding if getattr(a, "committed", False) else None,
+        params)
+    with obs_trace.span("engine.prepare_weights", cat="serve",
+                        bytes_in=_nbytes(params)) as sp:
+        out = jax.block_until_ready(
+            jax.jit(serve_weights, out_shardings=keep)(params))
+        sp.set(bytes_out=_nbytes(out))
+    return out
 
 
 @dataclass
@@ -119,7 +141,11 @@ class Engine:
                  tuning: Optional[bool] = None,
                  decode_collectives: str = "plan",
                  bundle=None):
-        """``batch_slots`` / ``n_blocks`` are PER DP SHARD; the global
+        """``params``: the model's (fp32 master) parameters; the engine
+        serves from a compute-dtype copy it makes once here
+        (:func:`~repro.models.model.serve_weights`) and keeps no
+        reference to ``params`` itself.
+        ``batch_slots`` / ``n_blocks`` are PER DP SHARD; the global
         batch is ``batch_slots * dp``.  ``n_blocks`` defaults to full
         residency (every slot can hold ``max_len`` tokens) + the garbage
         block; pass less to exercise admission under block pressure.
@@ -132,7 +158,7 @@ class Engine:
         if decode_collectives != pc.decode_collectives:
             pc = replace(pc, decode_collectives=decode_collectives)
         self.cfg, self.pc, self.mesh = cfg, pc, mesh
-        self.params = params
+        self.params = _prepare_weights(params)
         self.dp = max(pc.dp, 1)
         self.slots_per_shard = batch_slots
         self.B = batch_slots * self.dp

@@ -285,3 +285,48 @@ def test_compiled_programs_carry_the_named_scopes(tmp_path):
     scores = _instructions(serve, f"f32[{B},{hkv},{G},{S},{nb},{bs}]",
                            "dot")
     assert scores and all("/attn/" in n for n in scores), scores
+
+
+def test_the_serve_step_on_prepared_weights_casts_no_weight():
+    """Lowered on ``serve_weights`` of the parameters, as ``Engine``
+    serves, the paged serve step has no ``convert`` whose result is a
+    bf16 weight or one layer's slice of a stacked one; lowered on the
+    fp32 tree it has such converts, all under ``weight_cast``."""
+    import jax.numpy as jnp
+    from repro.configs import ARCHS, get_config, get_reduced
+    from repro.launch.mesh import make_mesh, parallel_config_for
+    from repro.models.attention import PageCtx
+    from repro.models.model import init_paged_caches, serve_weights
+    from repro.train.step import make_paged_serve_step
+
+    cfg = get_reduced(next(a for a in ARCHS if get_config(a).is_decoder))
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    pc = parallel_config_for(mesh, param_mode="dp")
+    sb = make_paged_serve_step(cfg, pc, mesh)
+    B, S, nb, bs = 2, 8, 4, 8
+    caches = jax.eval_shape(
+        lambda: init_paged_caches(cfg, pc, B, 1 + B * nb, bs))
+    ctx = PageCtx(block_table=jax.ShapeDtypeStruct((B, nb), jnp.int32),
+                  lengths=jax.ShapeDtypeStruct((B,), jnp.int32),
+                  n_new=jax.ShapeDtypeStruct((B,), jnp.int32),
+                  reset=jax.ShapeDtypeStruct((B,), jnp.bool_))
+    shapes = set()
+    for path, w in jax.tree_util.tree_flatten_with_path(
+            sb.params_shapes)[0]:
+        keys = [getattr(k, "key", getattr(k, "idx", None)) for k in path]
+        if keys[0] in ("embed", "head") or (
+                keys[0] in ("prefix", "cycles") and keys[2] in ("attn",
+                                                                "mlp")):
+            shapes.update(w.shape[i:] for i in range(len(w.shape) - 1))
+
+    def weight_converts(params):
+        hlo = sb.serve_step.lower(
+            params, jax.ShapeDtypeStruct((B, S), jnp.int32), caches,
+            ctx).as_text(dialect="hlo", debug_info=True)
+        return [n for s in shapes for n in _instructions(
+            hlo, "bf16[" + ",".join(map(str, s)) + "]", "convert")]
+
+    fp32 = weight_converts(sb.params_shapes)
+    assert fp32 and all("weight_cast" in n for n in fp32), fp32
+    assert not weight_converts(jax.eval_shape(serve_weights,
+                                              sb.params_shapes))
